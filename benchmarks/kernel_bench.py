@@ -106,36 +106,56 @@ def kernel_micro():
 # Paged-vs-dense sweep (PR 7: fused DMA + flash-decode split-KV)
 # ---------------------------------------------------------------------------
 
+def paged_from_dense(rng, lens, bs, kd, vd, kpos, pad=0):
+    """Scatter a dense ``(B, S)`` cache into a block pool.
+
+    The pool has ``B * S // bs + pad`` blocks in random order
+    (non-contiguous, like a live allocator).  Slot ``b``'s table maps the
+    ``ceil(lens[b] / bs)`` blocks that hold its tokens; every other pool
+    block is empty (keys and values 0, positions -1).  ``kpos`` must
+    already be -1 past each slot's length.  Returns
+    ``(k_pool, v_pool, pos_pool, block_tables)``.
+    """
+    B, S = kpos.shape
+    mbps = S // bs
+    nb = B * mbps + pad
+    perm = rng.permutation(nb)
+    bt = np.full((B, mbps), -1, np.int32)
+    for b, L in enumerate(lens):
+        n = -(-int(L) // bs)
+        bt[b, :n] = perm[b * mbps:b * mbps + n]
+    src = np.full(nb, B * mbps, np.int32)          # unmapped: empty block
+    mapped = bt >= 0
+    src[bt[mapped]] = (np.arange(B)[:, None] * mbps
+                       + np.arange(mbps)[None])[mapped]
+
+    def pool(x, fill):
+        x = jnp.asarray(x)
+        flat = x.reshape((B * mbps, bs) + x.shape[2:])
+        flat = jnp.concatenate(
+            [flat, jnp.full((1,) + flat.shape[1:], fill, flat.dtype)])
+        return flat[src]
+
+    return pool(kd, 0), pool(vd, 0), pool(kpos, -1), jnp.asarray(bt)
+
+
 def _build_paged(rng, B, bs, S, nkv, hd, tail=7):
     """Random paged pool + block tables + the gathered dense view.
 
-    Streams hold ``S - tail`` tokens so the last block is ragged; block
-    ids are a random permutation of the pool (non-contiguous, like a
-    live allocator), and ``pad`` extra pool blocks stay unmapped.
+    Streams hold ``S - tail`` tokens so the last block is ragged, and 4
+    extra pool blocks stay unmapped.
     """
-    mbps = S // bs
-    nb = mbps + 4
-    kp = rng.standard_normal((nb, bs, nkv, hd)).astype(np.float32)
-    vp = rng.standard_normal((nb, bs, nkv, hd)).astype(np.float32)
-    pos = np.full((nb, bs), -1, np.int32)
     L = S - tail
-    bt = np.full((B, mbps), -1, np.int32)
-    kd = np.zeros((B, S, nkv, hd), np.float32)
-    vd = np.zeros((B, S, nkv, hd), np.float32)
-    kpos = np.full((B, S), -1, np.int32)
-    for b in range(B):
-        perm = rng.permutation(nb)[: -(-L // bs)]
-        for j, blk in enumerate(perm):
-            base = j * bs
-            n = min(bs, L - base)
-            pos[blk, :n] = np.arange(base, base + n)
-            bt[b, j] = blk
-            kd[b, base:base + n] = kp[blk, :n]
-            vd[b, base:base + n] = vp[blk, :n]
-            kpos[b, base:base + n] = np.arange(base, base + n)
+    live = np.arange(S) < L
+    kd = np.where(live[None, :, None, None],
+                  rng.standard_normal((B, S, nkv, hd)), 0).astype(np.float32)
+    vd = np.where(live[None, :, None, None],
+                  rng.standard_normal((B, S, nkv, hd)), 0).astype(np.float32)
+    kpos = np.tile(np.where(live, np.arange(S), -1).astype(np.int32), (B, 1))
+    kp, vp, pos, bt = paged_from_dense(rng, [L] * B, bs, kd, vd, kpos, pad=4)
     J = jnp.asarray
-    return dict(k_pool=J(kp), v_pool=J(vp), pos_pool=J(pos), bt=J(bt),
-                kd=J(kd), vd=J(vd), kpos=J(kpos), L=L, mbps=mbps)
+    return dict(k_pool=kp, v_pool=vp, pos_pool=pos, bt=bt,
+                kd=J(kd), vd=J(vd), kpos=J(kpos), L=L, mbps=S // bs)
 
 
 def paged_micro(full: bool = True, n: int = 1):
@@ -287,6 +307,8 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     import sys
     if "--paged" in sys.argv:
         paged_main(full=True)

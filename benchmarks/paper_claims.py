@@ -77,10 +77,11 @@ def make_engine(llm_cfg, llm_p, slots: int = 2, attn_impl: str | None = None,
                 retain_blocks: int | None = None,
                 host_dedupe: bool | None = None,
                 paged_block_kv: int | None = None,
-                kv_splits: int | None = None):
+                kv_splits: int | None = None,
+                s_max: int = S_MAX, device=None):
     cfg = llm_cfg if attn_impl is None else llm_cfg.replace(
         attn_impl=attn_impl)
-    return CloudEngine(cfg, llm_p, max_slots=slots, s_max=S_MAX,
+    return CloudEngine(cfg, llm_p, max_slots=slots, s_max=s_max,
                        verify_top_k=verify_top_k, cache_impl=cache_impl,
                        block_size=block_size, pool_blocks=pool_blocks,
                        share_prefix=share_prefix, swap=swap,
@@ -88,12 +89,13 @@ def make_engine(llm_cfg, llm_p, slots: int = 2, attn_impl: str | None = None,
                        retain_prefix=retain_prefix,
                        retain_blocks=retain_blocks,
                        host_dedupe=host_dedupe,
-                       paged_block_kv=paged_block_kv, kv_splits=kv_splits)
+                       paged_block_kv=paged_block_kv, kv_splits=kv_splits,
+                       device=device)
 
 
-def profile_pair(dev, eng, evalset, task):
+def profile_pair(dev, eng, evalset, task, max_new: int = GEN):
     """Offline profiling (§5): offload-all calibration pass."""
-    r = SY.run_synera(dev, eng, [p for p, _ in evalset], GEN,
+    r = SY.run_synera(dev, eng, [p for p, _ in evalset], max_new,
                       profile_mode=True)
     recs = [c for m in r.metrics for c in m.chunk_records]
     return fit_profile(recs), r

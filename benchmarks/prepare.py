@@ -48,4 +48,6 @@ def get_pair(steps_slm: int = STEPS_SLM, steps_llm: int = STEPS_LLM,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     get_pair(force="--force" in __import__("sys").argv)

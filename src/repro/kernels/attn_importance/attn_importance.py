@@ -40,8 +40,8 @@ def _attn_imp_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref, imp_ref, *,
     q = q_ref[0].astype(jnp.float32) * scale            # (block_q, hd)
     k = k_ref[0].astype(jnp.float32)                     # (S, hd)
     v = v_ref[0].astype(jnp.float32)                     # (S, hd)
-    q_pos = qp_ref[0]                                    # (block_q,) int32
-    kv_pos = kp_ref[0]                                   # (S,) int32
+    q_pos = qp_ref[0, 0]                                 # (block_q,) int32
+    kv_pos = kp_ref[0, 0]                                # (S,) int32
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (block_q, S)
 
@@ -68,7 +68,7 @@ def _attn_imp_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref, imp_ref, *,
     def _init():
         imp_ref[...] = jnp.zeros_like(imp_ref)
 
-    imp_ref[0] += contrib.astype(imp_ref.dtype)
+    imp_ref[0] += contrib[None, :].astype(imp_ref.dtype)
 
 
 def attn_with_importance(q, k, v, q_pos=None, kv_pos=None, *,
@@ -112,6 +112,11 @@ def attn_with_importance(q, k, v, q_pos=None, kv_pos=None, *,
         q_pos = jnp.pad(q_pos, ((0, 0), (0, pad_q)), constant_values=-1)
     kh = jnp.moveaxis(k, 2, 1).reshape(B * nkv, S, hd)
     vh = jnp.moveaxis(v, 2, 1).reshape(B * nkv, S, hd)
+    # positions and importance ride as (·, 1, n) so every block's last
+    # two dims are (1, n): the TPU tiling accepts a dim equal to the
+    # array's
+    qp = q_pos.reshape(B, 1, n_qb * bq)
+    kp = kv_pos.reshape(B, 1, S)
 
     kernel = functools.partial(_attn_imp_kernel, causal=causal, scale=scale)
 
@@ -122,19 +127,20 @@ def attn_with_importance(q, k, v, q_pos=None, kv_pos=None, *,
             pl.BlockSpec((1, bq, hd), lambda bh, tb: (bh, tb, 0)),
             pl.BlockSpec((1, S, hd), lambda bh, tb, g=g: (bh // g, 0, 0)),
             pl.BlockSpec((1, S, hd), lambda bh, tb, g=g: (bh // g, 0, 0)),
-            pl.BlockSpec((1, bq), lambda bh, tb, nh=nh: (bh // nh, tb)),
-            pl.BlockSpec((1, S), lambda bh, tb, nh=nh: (bh // nh, 0)),
+            pl.BlockSpec((1, 1, bq),
+                         lambda bh, tb, nh=nh: (bh // nh, 0, tb)),
+            pl.BlockSpec((1, 1, S), lambda bh, tb, nh=nh: (bh // nh, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, hd), lambda bh, tb: (bh, tb, 0)),
-            pl.BlockSpec((1, S), lambda bh, tb: (bh, 0)),
+            pl.BlockSpec((1, 1, S), lambda bh, tb: (bh, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * nh, n_qb * bq, hd), q.dtype),
-            jax.ShapeDtypeStruct((B * nh, S), jnp.float32),
+            jax.ShapeDtypeStruct((B * nh, 1, S), jnp.float32),
         ],
         interpret=interpret,
-    )(qh, kh, vh, q_pos, kv_pos)
+    )(qh, kh, vh, qp, kp)
 
     out = out[:, :Tq].reshape(B, nh, Tq, hd)
     out = jnp.moveaxis(out, 1, 2)

@@ -40,8 +40,8 @@ def _decode_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref,
     q = q_ref[0].astype(jnp.float32) * scale      # (g, hd)
     k = k_ref[0].astype(jnp.float32)               # (bkv, hd) loaded once
     v = v_ref[0].astype(jnp.float32)               # (bkv, hd)
-    q_pos = qp_ref[0, 0]                           # scalar
-    kv_pos = kp_ref[0]                             # (bkv,)
+    q_pos = qp_ref[0, 0]                           # (1,)
+    kv_pos = kp_ref[0, 0]                          # (bkv,)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (g, bkv)
     valid = (kv_pos >= 0) & (kv_pos <= q_pos)
@@ -94,7 +94,10 @@ def decode_attention(q, k, v, q_pos, kv_pos, *, window: int = 0,
     qg = q.reshape(B * nkv, g, hd)
     kh = jnp.moveaxis(k, 2, 1).reshape(B * nkv, S, hd)
     vh = jnp.moveaxis(v, 2, 1).reshape(B * nkv, S, hd)
-    qp = q_pos.reshape(B, 1).astype(jnp.int32)
+    # positions ride as (B, 1, n) so every block's last two dims are
+    # (1, n): the TPU tiling accepts a dim equal to the array's
+    qp = q_pos.reshape(B, 1, 1).astype(jnp.int32)
+    kp = kv_pos.reshape(B, 1, S)
 
     kernel = functools.partial(_decode_kernel, n_kvb=n_kvb, window=window,
                                scale=scale)
@@ -106,8 +109,10 @@ def decode_attention(q, k, v, q_pos, kv_pos, *, window: int = 0,
             pl.BlockSpec((1, g, hd), lambda bk, sb: (bk, 0, 0)),
             pl.BlockSpec((1, bkv, hd), lambda bk, sb: (bk, sb, 0)),
             pl.BlockSpec((1, bkv, hd), lambda bk, sb: (bk, sb, 0)),
-            pl.BlockSpec((1, 1), lambda bk, sb, nkv=nkv: (bk // nkv, 0)),
-            pl.BlockSpec((1, bkv), lambda bk, sb, nkv=nkv: (bk // nkv, sb)),
+            pl.BlockSpec((1, 1, 1),
+                         lambda bk, sb, nkv=nkv: (bk // nkv, 0, 0)),
+            pl.BlockSpec((1, 1, bkv),
+                         lambda bk, sb, nkv=nkv: (bk // nkv, 0, sb)),
         ],
         out_specs=pl.BlockSpec((1, g, hd), lambda bk, sb: (bk, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B * nkv, g, hd), q.dtype),
@@ -117,7 +122,7 @@ def decode_attention(q, k, v, q_pos, kv_pos, *, window: int = 0,
             pltpu.VMEM((g, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(qg, kh, vh, qp, kv_pos)
+    )(qg, kh, vh, qp, kp)
 
     return out.reshape(B, nh, hd)
 
@@ -161,34 +166,26 @@ def _decode_paged_kernel(bt_ref, q_ref, *refs, fuse: int, spb: int,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0].astype(jnp.float32) * scale       # (g, hd)
-    q_pos = qp_ref[0, 0]
+    q_pos = qp_ref[0]                              # (1, 1)
     slot = bk // nkv
     base = (sp * spb + sb) * fuse                  # first table entry here
 
-    ks, vs, valids = [], [], []
-    for j in range(fuse):
-        # per-sub-block mapped mask (replaces the unfused kernel's
-        # single ``mapped`` scalar): entry within table AND mapped
-        mapped = PG.subblock_mapped(bt_ref, slot, base + j, max_bps)
-        kv_pos = kp_refs[j][0]                     # (bs,)
-        val = mapped & (kv_pos >= 0) & (kv_pos <= q_pos)
-        if window:
-            val &= (q_pos - kv_pos) < window
-        ks.append(k_refs[j][0, 0])
-        vs.append(v_refs[j][0, 0])
-        valids.append(val)
-    k = jnp.concatenate(ks, axis=0).astype(jnp.float32)   # (fuse*bs, hd)
-    v = jnp.concatenate(vs, axis=0).astype(jnp.float32)
-    valid = jnp.concatenate(valids, axis=0)               # (fuse*bs,)
+    k = jnp.concatenate([r[0, 0] for r in k_refs],
+                        axis=0).astype(jnp.float32)  # (fuse*bs, hd)
+    v = jnp.concatenate([r[0, 0] for r in v_refs], axis=0).astype(jnp.float32)
+    kv_pos = PG.fused_positions(bt_ref, kp_refs, slot, base, max_bps)
+    valid = (kv_pos >= 0) & (kv_pos <= q_pos)             # (1, fuse*bs)
+    if window:
+        valid &= (q_pos - kv_pos) < window
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (g, fuse*bs)
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev, l_prev, acc_prev = m_scr[...], l_scr[...], acc_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
     corr = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new[:, None])
-    p = jnp.where(valid[None, :], p, 0.0)
+    p = jnp.where(valid, p, 0.0)
     l_new = l_prev * corr + jnp.sum(p, axis=1)
     acc_new = acc_prev * corr[:, None] + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())))
@@ -199,8 +196,8 @@ def _decode_paged_kernel(bt_ref, q_ref, *refs, fuse: int, spb: int,
 
     @pl.when(sb == spb - 1)
     def _finish():
-        om_ref[0, 0] = m_new
-        ol_ref[0, 0] = l_new
+        om_ref[0, 0] = m_new[None, :]
+        ol_ref[0, 0] = l_new[None, :]
         oa_ref[0, 0] = acc_new
 
 
@@ -235,7 +232,8 @@ def decode_attention_paged(q, k_pool, v_pool, q_pos, pos_pool, block_tables,
     qg = q.reshape(B * nkv, g, hd)
     kh = jnp.moveaxis(k_pool, 2, 1)                # (nb, nkv, bs, hd)
     vh = jnp.moveaxis(v_pool, 2, 1)
-    qp = q_pos.reshape(B, 1).astype(jnp.int32)
+    qp = q_pos.reshape(B, 1, 1).astype(jnp.int32)
+    pp = pos_pool.reshape(nb, 1, bs)
     bt = block_tables.astype(jnp.int32)
 
     kernel = functools.partial(_decode_paged_kernel, fuse=fuse, spb=spb,
@@ -252,7 +250,7 @@ def decode_attention_paged(q, k_pool, v_pool, q_pos, pos_pool, block_tables,
     def pos_map(j, nkv=nkv):
         def m(bk, sp, sb, bt):
             e = (sp * spb + sb) * fuse + j
-            return (PG.table_entry(bt, bk // nkv, e, max_bps), 0)
+            return (PG.table_entry(bt, bk // nkv, e, max_bps), 0, 0)
         return m
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -262,13 +260,15 @@ def decode_attention_paged(q, k_pool, v_pool, q_pos, pos_pool, block_tables,
             pl.BlockSpec((1, g, hd), lambda bk, sp, sb, bt: (bk, 0, 0)),
             *[pl.BlockSpec((1, 1, bs, hd), kv_map(j)) for j in range(fuse)],
             *[pl.BlockSpec((1, 1, bs, hd), kv_map(j)) for j in range(fuse)],
-            pl.BlockSpec((1, 1),
-                         lambda bk, sp, sb, bt, nkv=nkv: (bk // nkv, 0)),
-            *[pl.BlockSpec((1, bs), pos_map(j)) for j in range(fuse)],
+            pl.BlockSpec((1, 1, 1),
+                         lambda bk, sp, sb, bt, nkv=nkv: (bk // nkv, 0, 0)),
+            *[pl.BlockSpec((1, 1, bs), pos_map(j)) for j in range(fuse)],
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, g), lambda bk, sp, sb, bt: (bk, sp, 0)),
-            pl.BlockSpec((1, 1, g), lambda bk, sp, sb, bt: (bk, sp, 0)),
+            pl.BlockSpec((1, 1, 1, g),
+                         lambda bk, sp, sb, bt: (bk, sp, 0, 0)),
+            pl.BlockSpec((1, 1, 1, g),
+                         lambda bk, sp, sb, bt: (bk, sp, 0, 0)),
             pl.BlockSpec((1, 1, g, hd),
                          lambda bk, sp, sb, bt: (bk, sp, 0, 0)),
         ],
@@ -283,14 +283,14 @@ def decode_attention_paged(q, k_pool, v_pool, q_pos, pos_pool, block_tables,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B * nkv, splits, g), jnp.float32),
-            jax.ShapeDtypeStruct((B * nkv, splits, g), jnp.float32),
+            jax.ShapeDtypeStruct((B * nkv, splits, 1, g), jnp.float32),
+            jax.ShapeDtypeStruct((B * nkv, splits, 1, g), jnp.float32),
             jax.ShapeDtypeStruct((B * nkv, splits, g, hd), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(bt, qg, *[kh] * fuse, *[vh] * fuse, qp, *[pos_pool] * fuse)
+    )(bt, qg, *[kh] * fuse, *[vh] * fuse, qp, *[pp] * fuse)
 
-    out = PG.combine_splits(m, l, acc, q.dtype)
+    out = PG.combine_splits(m[:, :, 0], l[:, :, 0], acc, q.dtype)
     return out.reshape(B, nh, hd)

@@ -58,6 +58,17 @@ def subblock_mapped(bt_ref, slot, entry, max_bps: int):
         bt_ref[slot, jnp.minimum(entry, max_bps - 1)] >= 0)
 
 
+def fused_positions(bt_ref, kp_refs, slot, base, max_bps: int):
+    """Positions of one fused grid step's ``fuse`` sub-blocks as one
+    (1, fuse * bs) int32 row; a sub-block whose table entry is unmapped
+    or past the table reads as -1 (invalid), so the caller's ``pos >= 0``
+    test masks it whole.  The rows are joined along lanes in int32: the
+    TPU compiler accepts that join, and not one of boolean masks."""
+    return jnp.concatenate(
+        [jnp.where(subblock_mapped(bt_ref, slot, base + j, max_bps),
+                   r[0], -1) for j, r in enumerate(kp_refs)], axis=1)
+
+
 def fused_layout(max_bps: int, block_size: int, block_kv: int | None,
                  kv_splits: int = 1):
     """Resolve the fused/split grid layout for a paged kernel.

@@ -46,8 +46,8 @@ def _pp_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref,
     q = q_ref[0].astype(jnp.float32) * scale      # (C, hd)
     k = k_ref[0].astype(jnp.float32)               # (bkv, hd)
     v = v_ref[0].astype(jnp.float32)               # (bkv, hd)
-    q_pos = qp_ref[0]                              # (C,) int32
-    kv_pos = kp_ref[0]                             # (bkv,) int32
+    q_pos = qp_ref[0, 0]                           # (C,) int32
+    kv_pos = kp_ref[0, 0]                          # (bkv,) int32
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (C, bkv)
     valid = (kv_pos[None, :] >= 0) & (q_pos[:, None] >= 0) \
@@ -102,6 +102,10 @@ def partial_prefill_attention(q, k, v, q_pos, kv_pos, *, window: int = 0,
     qh = jnp.moveaxis(q, 2, 1).reshape(B * nh, C, hd)
     kh = jnp.moveaxis(k, 2, 1).reshape(B * nkv, S, hd)
     vh = jnp.moveaxis(v, 2, 1).reshape(B * nkv, S, hd)
+    # positions ride as (B, 1, n) so every block's last two dims are
+    # (1, n): the TPU tiling accepts a dim equal to the array's
+    qp = q_pos.reshape(B, 1, C)
+    kp = kv_pos.reshape(B, 1, S)
 
     kernel = functools.partial(_pp_kernel, n_kvb=n_kvb, window=window,
                                scale=scale)
@@ -113,8 +117,9 @@ def partial_prefill_attention(q, k, v, q_pos, kv_pos, *, window: int = 0,
             pl.BlockSpec((1, C, hd), lambda bh, sb: (bh, 0, 0)),
             pl.BlockSpec((1, bkv, hd), lambda bh, sb, g=g: (bh // g, sb, 0)),
             pl.BlockSpec((1, bkv, hd), lambda bh, sb, g=g: (bh // g, sb, 0)),
-            pl.BlockSpec((1, C), lambda bh, sb, nh=nh: (bh // nh, 0)),
-            pl.BlockSpec((1, bkv), lambda bh, sb, nh=nh: (bh // nh, sb)),
+            pl.BlockSpec((1, 1, C), lambda bh, sb, nh=nh: (bh // nh, 0, 0)),
+            pl.BlockSpec((1, 1, bkv),
+                         lambda bh, sb, nh=nh: (bh // nh, 0, sb)),
         ],
         out_specs=pl.BlockSpec((1, C, hd), lambda bh, sb: (bh, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B * nh, C, hd), q.dtype),
@@ -124,7 +129,7 @@ def partial_prefill_attention(q, k, v, q_pos, kv_pos, *, window: int = 0,
             pltpu.VMEM((C, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(qh, kh, vh, q_pos, kv_pos)
+    )(qh, kh, vh, qp, kp)
 
     return jnp.moveaxis(out.reshape(B, nh, C, hd), 1, 2)
 
@@ -157,26 +162,18 @@ def _pp_paged_kernel(bt_ref, q_ref, *refs, fuse: int, spb: int,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0].astype(jnp.float32) * scale       # (C, hd)
-    q_pos = qp_ref[0]                              # (C,)
+    q_pos = qp_ref[0, 0]                           # (C,)
     slot = bh // nh
     base = (sp * spb + sb) * fuse                  # first table entry here
 
-    ks, vs, valids = [], [], []
-    for j in range(fuse):
-        # per-sub-block mapped mask (replaces the unfused kernel's
-        # single ``mapped`` scalar): entry within table AND mapped
-        mapped = PG.subblock_mapped(bt_ref, slot, base + j, max_bps)
-        kv_pos = kp_refs[j][0]                     # (bs,)
-        val = mapped & (kv_pos[None, :] >= 0) & (q_pos[:, None] >= 0) \
-            & (kv_pos[None, :] <= q_pos[:, None])
-        if window:
-            val &= (q_pos[:, None] - kv_pos[None, :]) < window
-        ks.append(k_refs[j][0, 0])
-        vs.append(v_refs[j][0, 0])
-        valids.append(val)                         # (C, bs)
-    k = jnp.concatenate(ks, axis=0).astype(jnp.float32)   # (fuse*bs, hd)
-    v = jnp.concatenate(vs, axis=0).astype(jnp.float32)
-    valid = jnp.concatenate(valids, axis=1)               # (C, fuse*bs)
+    k = jnp.concatenate([r[0, 0] for r in k_refs],
+                        axis=0).astype(jnp.float32)  # (fuse*bs, hd)
+    v = jnp.concatenate([r[0, 0] for r in v_refs], axis=0).astype(jnp.float32)
+    kv_pos = PG.fused_positions(bt_ref, kp_refs, slot, base, max_bps)
+    valid = (kv_pos >= 0) & (q_pos[:, None] >= 0) \
+        & (kv_pos <= q_pos[:, None])                      # (C, fuse*bs)
+    if window:
+        valid &= (q_pos[:, None] - kv_pos) < window
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (C, fuse*bs)
     s = jnp.where(valid, s, NEG_INF)
@@ -196,8 +193,8 @@ def _pp_paged_kernel(bt_ref, q_ref, *refs, fuse: int, spb: int,
 
     @pl.when(sb == spb - 1)
     def _finish():
-        om_ref[0, 0] = m_new
-        ol_ref[0, 0] = l_new
+        om_ref[0, 0] = m_new[None, :]
+        ol_ref[0, 0] = l_new[None, :]
         oa_ref[0, 0] = acc_new
 
 
@@ -229,6 +226,8 @@ def partial_prefill_attention_paged(q, k_pool, v_pool, q_pos, pos_pool,
     qh = jnp.moveaxis(q, 2, 1).reshape(B * nh, C, hd)
     kh = jnp.moveaxis(k_pool, 2, 1)                # (nb, nkv, bs, hd)
     vh = jnp.moveaxis(v_pool, 2, 1)
+    qp = q_pos.reshape(B, 1, C)
+    pp = pos_pool.reshape(nb, 1, bs)
     bt = block_tables.astype(jnp.int32)
 
     kernel = functools.partial(_pp_paged_kernel, fuse=fuse, spb=spb,
@@ -245,7 +244,7 @@ def partial_prefill_attention_paged(q, k_pool, v_pool, q_pos, pos_pool,
     def pos_map(j, nh=nh):
         def m(bh, sp, sb, bt):
             e = (sp * spb + sb) * fuse + j
-            return (PG.table_entry(bt, bh // nh, e, max_bps), 0)
+            return (PG.table_entry(bt, bh // nh, e, max_bps), 0, 0)
         return m
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -255,13 +254,15 @@ def partial_prefill_attention_paged(q, k_pool, v_pool, q_pos, pos_pool,
             pl.BlockSpec((1, C, hd), lambda bh, sp, sb, bt: (bh, 0, 0)),
             *[pl.BlockSpec((1, 1, bs, hd), kv_map(j)) for j in range(fuse)],
             *[pl.BlockSpec((1, 1, bs, hd), kv_map(j)) for j in range(fuse)],
-            pl.BlockSpec((1, C),
-                         lambda bh, sp, sb, bt, nh=nh: (bh // nh, 0)),
-            *[pl.BlockSpec((1, bs), pos_map(j)) for j in range(fuse)],
+            pl.BlockSpec((1, 1, C),
+                         lambda bh, sp, sb, bt, nh=nh: (bh // nh, 0, 0)),
+            *[pl.BlockSpec((1, 1, bs), pos_map(j)) for j in range(fuse)],
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, C), lambda bh, sp, sb, bt: (bh, sp, 0)),
-            pl.BlockSpec((1, 1, C), lambda bh, sp, sb, bt: (bh, sp, 0)),
+            pl.BlockSpec((1, 1, 1, C),
+                         lambda bh, sp, sb, bt: (bh, sp, 0, 0)),
+            pl.BlockSpec((1, 1, 1, C),
+                         lambda bh, sp, sb, bt: (bh, sp, 0, 0)),
             pl.BlockSpec((1, 1, C, hd),
                          lambda bh, sp, sb, bt: (bh, sp, 0, 0)),
         ],
@@ -276,14 +277,14 @@ def partial_prefill_attention_paged(q, k_pool, v_pool, q_pos, pos_pool,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B * nh, splits, C), jnp.float32),
-            jax.ShapeDtypeStruct((B * nh, splits, C), jnp.float32),
+            jax.ShapeDtypeStruct((B * nh, splits, 1, C), jnp.float32),
+            jax.ShapeDtypeStruct((B * nh, splits, 1, C), jnp.float32),
             jax.ShapeDtypeStruct((B * nh, splits, C, hd), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(bt, qh, *[kh] * fuse, *[vh] * fuse, q_pos, *[pos_pool] * fuse)
+    )(bt, qh, *[kh] * fuse, *[vh] * fuse, qp, *[pp] * fuse)
 
-    out = PG.combine_splits(m, l, acc, q.dtype)
+    out = PG.combine_splits(m[:, :, 0], l[:, :, 0], acc, q.dtype)
     return jnp.moveaxis(out.reshape(B, nh, C, hd), 1, 2)

@@ -189,6 +189,8 @@ def main():
                          "PATH after the run (implies --trace); load it "
                          "at ui.perfetto.dev")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     trace_on = args.trace or bool(args.trace_out)
     if args.concurrency < 0:
         ap.error("--concurrency must be >= 0 (0 = unbounded)")
@@ -222,7 +224,11 @@ def main():
     link = LinkModel(bandwidth_mbps=args.bandwidth_mbps)
     if args.swap and args.cache_impl != "paged":
         ap.error("--swap requires --cache-impl paged")
-    def mk_engine():
+    import jax
+    devices = jax.devices()
+
+    def mk_engine(i=0):
+        # replica i serves from its own device where the host has several
         return PC.make_engine(llm_cfg, llm_p, slots=args.slots,
                               attn_impl=args.attn_impl,
                               verify_top_k=args.verify_top_k,
@@ -236,12 +242,14 @@ def main():
                               retain_blocks=args.retain_blocks,
                               host_dedupe=args.host_dedupe,
                               paged_block_kv=args.block_kv,
-                              kv_splits=args.kv_splits)
+                              kv_splits=args.kv_splits,
+                              device=(devices[i % len(devices)]
+                                      if args.replicas > 1 else None))
 
     eng = mk_engine()
     # fleet mode: replica 0 reuses `eng` (also the profiling target);
     # the rest are independent engines with their own pools and caches
-    engines = ([eng] + [mk_engine() for _ in range(args.replicas - 1)]
+    engines = ([eng] + [mk_engine(i) for i in range(1, args.replicas)]
                if args.replicas > 1 else [eng])
     concurrency = None if args.concurrency == 0 else args.concurrency
     arrivals = None

@@ -194,7 +194,8 @@ def make_cloud_verify_step(cfg, *, window: int = 0, top_k: int = 8,
     vocab-sized tensor to the host.
     """
 
-    def step(params, cache, tokens, pos, targets, sel_idx, last_local):
+    def cloud_verify(params, cache, tokens, pos, targets, sel_idx,
+                     last_local):
         logits, cache, _, _ = M.forward(cfg, params, tokens, pos, cache=cache,
                                         window=window)
         tok, p_t, tk_i, tk_v = fused_verify_epilogue(
@@ -203,7 +204,7 @@ def make_cloud_verify_step(cfg, *, window: int = 0, top_k: int = 8,
             logits, last_local[:, None, None].astype(jnp.int32), axis=1)[:, 0]
         return (tok, p_t, tk_i, tk_v, last.astype(jnp.float32)), cache
 
-    return step
+    return cloud_verify
 
 
 def make_cloud_decode_step(cfg, *, window: int = 0, top_k: int = 8):
@@ -214,7 +215,7 @@ def make_cloud_decode_step(cfg, *, window: int = 0, top_k: int = 8):
         ((token_id (B,), topk_idx (B,K), topk_val (B,K)), cache)
     """
 
-    def step(params, cache, token, pos):
+    def cloud_decode(params, cache, token, pos):
         logits, cache, _, _ = M.forward(cfg, params, token, pos, cache=cache,
                                         window=window)
         row = logits[:, -1].astype(jnp.float32)
@@ -226,7 +227,7 @@ def make_cloud_decode_step(cfg, *, window: int = 0, top_k: int = 8):
         tk_v = jnp.exp(tkl - lse[..., None])
         return (tok, tk_i.astype(jnp.int32), tk_v), cache
 
-    return step
+    return cloud_decode
 
 
 def make_device_draft_step(cfg):

@@ -130,7 +130,7 @@ def _make_device_step(cfg):
     """jit-able single-token step returning per-layer last-position logits
     (for early exit), mean importance over the cache, and the new cache."""
 
-    def step(params, cache, token, pos):
+    def draft_step(params, cache, token, pos):
         h = jnp.take(params["embed"], token, axis=0)  # (1, 1, d)
 
         def body(hh, xs):
@@ -147,7 +147,7 @@ def _make_device_step(cfg):
         imp_mean = imps.mean(axis=0)[0]             # (S,) over cache slots
         return layer_logits, imp_mean, {"layers": ncache}
 
-    return step
+    return draft_step
 
 
 class DeviceRuntime:
@@ -193,8 +193,11 @@ class DeviceRuntime:
         self.wire_vocab = wire_vocab or self.cfg.vocab
 
         self._step = jax.jit(_make_device_step(self.cfg))
-        self._prefill = jax.jit(
-            lambda p, c, t, pos: M.forward(self.cfg, p, t, pos, cache=c)[:2])
+
+        def draft_prefill(p, c, t, pos):
+            return M.forward(self.cfg, p, t, pos, cache=c)[:2]
+
+        self._prefill = jax.jit(draft_prefill)
 
     # ------------------------------------------------------------------
     def _sample(self, logits: np.ndarray) -> int:

@@ -20,13 +20,18 @@ Device-residency contract (the serving hot path, docs/serving_api.md):
   last prompt position, gathered on device), which seeds the sampling
   verifier's pre-draft row; this is per-prefill, not per-iteration.
 * The cache pytree is donated to every step (``donate_argnums``), so
-  feed/decode/verify update it in place on backends that support
-  donation, and ``reset_slot`` is a single jitted slot-masked update
-  (one dispatch) instead of a host tree walk.
+  feed/decode/verify update it in place (on the CPU too; a missed
+  donation warns, since it means a second copy of the pool), and
+  ``reset_slot`` is a single jitted slot-masked update (one dispatch)
+  instead of a host tree walk.
 * ``feed_logits`` / ``decode_logits`` are the legacy/debug path that
   does round-trip the full (slots, chunk, V) tensor — kept for
   before/after benchmarking (benchmarks/hotpath_bench.py) and the
   fused-vs-host-numpy identity tests.
+
+An engine given a ``device`` keeps its weights and its cache on that
+device, and every host input goes there directly, so the replicas of a
+fleet each serve from their own chip.
 
 Ragged per-slot chunks are padded to the iteration width; padded entries
 carry position -1, which ``cache_write`` drops (never pollutes the
@@ -37,7 +42,7 @@ specializations actually taken.
 """
 from __future__ import annotations
 
-import warnings
+import contextlib
 from collections import deque
 from dataclasses import dataclass
 
@@ -537,17 +542,6 @@ def _set_block_tables(cache, table):
     return walk(cache)
 
 
-def _call_donated(fn, *args):
-    """Invoke a donated jitted step.  CPU (and some other backends)
-    silently ignore buffer donation; the per-compilation warning is not
-    actionable here, and the suppression stays scoped to this call so
-    the process-global warning state is untouched."""
-    with warnings.catch_warnings():
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-        return fn(*args)
-
-
 @dataclass(frozen=True)
 class VerifyRows:
     """Fused verification state for one feed (host-resident).
@@ -625,7 +619,8 @@ class CloudEngine:
                  swap: bool | None = None,
                  host_swap_blocks: int | None = None,
                  paged_block_kv: int | None = None,
-                 kv_splits: int | None = None):
+                 kv_splits: int | None = None,
+                 device=None):
         # paged-kernel streaming knobs (fused-DMA width / flash-decode
         # split-KV) ride on the config so the jitted steps see them
         if paged_block_kv is not None:
@@ -633,7 +628,11 @@ class CloudEngine:
         if kv_splits is not None:
             cfg = cfg.replace(paged_kv_splits=kv_splits)
         self.cfg = cfg
-        self.params = params
+        # ``device`` (a jax Device) commits weights, cache and every host
+        # input to that device; None keeps JAX's default placement
+        self.device = device
+        self.params = (params if device is None
+                       else jax.device_put(params, device))
         self.max_slots = max_slots
         self.s_max = s_max
         self.window = window
@@ -655,10 +654,18 @@ class CloudEngine:
         if want_swap and self.cache_impl != "paged":
             raise ValueError("swap=True requires cache_impl='paged' "
                              "(dense caches have no block pool to swap)")
+        max_bps = -(-s_max // self.block_size)
+        nb = pool_blocks if pool_blocks is not None else max_slots * max_bps
+        # the cache is built on the engine's device, never staged elsewhere
+        with (jax.default_device(device) if device is not None
+              else contextlib.nullcontext()):
+            self.cache = M.init_cache(cfg, max_slots, s_max,
+                                      cache_impl=self.cache_impl,
+                                      block_size=self.block_size,
+                                      pool_blocks=nb)
+        if device is not None:
+            self.cache = self._put(self.cache)
         if self.cache_impl == "paged":
-            max_bps = -(-s_max // self.block_size)
-            nb = (pool_blocks if pool_blocks is not None
-                  else max_slots * max_bps)
             retain = bool(retain_prefix if retain_prefix is not None
                           else getattr(cfg, "retain_prefix", False))
             self.share_prefix = bool(
@@ -670,10 +677,6 @@ class CloudEngine:
                 retain_prefix=retain,
                 retain_blocks=(retain_blocks if retain_blocks is not None
                                else getattr(cfg, "retain_blocks", 0)))
-            self.cache = M.init_cache(cfg, max_slots, s_max,
-                                      cache_impl="paged",
-                                      block_size=self.block_size,
-                                      pool_blocks=nb)
             self._reset_blocks = jax.jit(_reset_paged_blocks,
                                          donate_argnums=0)
             self._copy_blocks = jax.jit(M.copy_cache_blocks,
@@ -691,8 +694,6 @@ class CloudEngine:
                 self.swap_manager = HostSwapManager(self,
                                                     max_host_blocks=hb,
                                                     host_dedupe=dedupe)
-        else:
-            self.cache = M.init_cache(cfg, max_slots, s_max)
         self._step = jax.jit(
             make_cloud_verify_step(cfg, window=window,
                                    top_k=self.verify_top_k),
@@ -721,6 +722,10 @@ class CloudEngine:
         # fault injection (serving/router.py): a replica marked dead must
         # never serve again — any further compute dispatch raises
         self.dead = False
+
+    def _put(self, x):
+        """Place a host array (or pytree) on this engine's device."""
+        return jax.device_put(x, self.device)
 
     def mark_dead(self):
         """Poison this engine: every subsequent compute dispatch raises.
@@ -767,7 +772,7 @@ class CloudEngine:
             self._tables_dirty = True
             self._sync_tables()
             return
-        self.cache = _call_donated(self._reset, self.cache, jnp.int32(slot))
+        self.cache = self._reset(self.cache, self._put(np.int32(slot)))
 
     # -- paged block management ----------------------------------------
     def _invalidate_blocks(self, bids):
@@ -782,8 +787,7 @@ class CloudEngine:
             grp = bids[off:off + W]
             pad = np.full(W, -1, np.int32)
             pad[:len(grp)] = grp
-            self.cache = _call_donated(self._reset_blocks, self.cache,
-                                       jnp.asarray(pad))
+            self.cache = self._reset_blocks(self.cache, self._put(pad))
 
     def _flush_reclaims(self):
         """Invalidate positions of blocks reclaimed from the cached-free
@@ -802,7 +806,7 @@ class CloudEngine:
         ``block_tables`` cache leaf (host-side leaf swap, no jit)."""
         if self.allocator is not None and self._tables_dirty:
             self.cache = _set_block_tables(
-                self.cache, jnp.asarray(self.allocator.table))
+                self.cache, self._put(self.allocator.table))
             self._tables_dirty = False
 
     def _ensure_blocks(self, positions: np.ndarray):
@@ -857,8 +861,8 @@ class CloudEngine:
             dst = np.full(W, -1, np.int32)
             src[:len(grp)] = [s for s, _ in grp]
             dst[:len(grp)] = [d for _, d in grp]
-            self.cache = _call_donated(self._copy_blocks, self.cache,
-                                       jnp.asarray(src), jnp.asarray(dst))
+            self.cache = self._copy_blocks(self.cache, self._put(src),
+                                           self._put(dst))
 
     def alloc_prompt(self, slot: int, tokens, bids: list | None = None) -> int:
         """Allocate a freshly admitted slot's prompt blocks, deduping
@@ -919,9 +923,8 @@ class CloudEngine:
             dst = np.full(W, -1, np.int32)
             nrows = np.zeros(W, np.int32)
             src[0], dst[0], nrows[0] = src_bid, dst_bid, rows
-            self.cache = _call_donated(self._copy_rows, self.cache,
-                                       jnp.asarray(src), jnp.asarray(dst),
-                                       jnp.asarray(nrows))
+            self.cache = self._copy_rows(self.cache, self._put(src),
+                                         self._put(dst), self._put(nrows))
             a.tail_shared_tokens += rows
             shared += rows
         a.register_prefix(slot, tokens)
@@ -1048,13 +1051,13 @@ class CloudEngine:
         self._specializations.add(
             ("fused" if with_dists else "fused_greedy", Cb))
         step = self._step if with_dists else self._step_greedy
-        out, self.cache = _call_donated(
-            step, self.params, self.cache,
-            jnp.asarray(self._pad(tokens, Cb, 0), jnp.int32),
-            jnp.asarray(self._pad(positions, Cb, -1), jnp.int32),
-            jnp.asarray(self._pad(targets, Cb, -1), jnp.int32),
-            jnp.asarray(sel_idx, jnp.int32),
-            jnp.asarray(last_local, jnp.int32))
+        out, self.cache = step(
+            self.params, self.cache,
+            self._put(self._pad(tokens, Cb, 0).astype(np.int32)),
+            self._put(self._pad(positions, Cb, -1).astype(np.int32)),
+            self._put(self._pad(targets, Cb, -1).astype(np.int32)),
+            self._put(np.asarray(sel_idx, np.int32)),
+            self._put(np.asarray(last_local, np.int32)))
         return out
 
     # ------------------------------------------------------------------
@@ -1145,7 +1148,7 @@ class CloudEngine:
                 # sub-chunk
                 idx = np.where(sel)[0]
                 rows = np.asarray(
-                    jnp.take(res[4], jnp.asarray(idx, jnp.int32), axis=0),
+                    jnp.take(res[4], self._put(idx.astype(np.int32)), axis=0),
                     np.float32)
                 out[idx] = rows
                 self.bytes_to_host += rows.nbytes
@@ -1158,9 +1161,10 @@ class CloudEngine:
         self._calls["decode"] += 1
         self._ensure_blocks(positions)
         self._specializations.add(("decode", 1))
-        (tok, tk_i, tk_v), self.cache = _call_donated(
-            self._decode, self.params, self.cache,
-            jnp.asarray(tokens, jnp.int32), jnp.asarray(positions, jnp.int32))
+        (tok, tk_i, tk_v), self.cache = self._decode(
+            self.params, self.cache,
+            self._put(np.asarray(tokens, np.int32)),
+            self._put(np.asarray(positions, np.int32)))
         rows = DecodeRows(token_id=np.asarray(tok, np.int32),
                           topk_idx=np.asarray(tk_i, np.int32),
                           topk_val=np.asarray(tk_v, np.float32))
@@ -1179,10 +1183,11 @@ class CloudEngine:
             sl = slice(off, off + w)
             Cb = self._bucket_of(w)
             self._specializations.add(("raw", Cb))
-            logits, self.cache = _call_donated(
-                self._raw_verify, self.params, self.cache,
-                jnp.asarray(self._pad(tokens[:, sl], Cb, 0), jnp.int32),
-                jnp.asarray(self._pad(positions[:, sl], Cb, -1), jnp.int32))
+            logits, self.cache = self._raw_verify(
+                self.params, self.cache,
+                self._put(self._pad(tokens[:, sl], Cb, 0).astype(np.int32)),
+                self._put(self._pad(positions[:, sl], Cb, -1).astype(
+                    np.int32)))
             parts.append(np.asarray(logits[:, :w], np.float32))
         out = np.concatenate(parts, axis=1)
         self.bytes_to_host += out.nbytes
@@ -1194,9 +1199,10 @@ class CloudEngine:
         self._calls["decode_logits"] += 1
         self._ensure_blocks(positions)
         self._specializations.add(("raw_decode", 1))
-        logits, self.cache = _call_donated(
-            self._raw_decode, self.params, self.cache,
-            jnp.asarray(tokens, jnp.int32), jnp.asarray(positions, jnp.int32))
+        logits, self.cache = self._raw_decode(
+            self.params, self.cache,
+            self._put(np.asarray(tokens, np.int32)),
+            self._put(np.asarray(positions, np.int32)))
         out = np.asarray(logits, np.float32)
         self.bytes_to_host += out.nbytes
         return out

@@ -616,10 +616,14 @@ class VerificationAwareScheduler:
             # restartable): defer the youngest feeding chunk that
             # actually needs blocks — zero-demand chunks write into
             # their last partial block and can always proceed (and
-            # finishing them is what releases blocks).  The deferred
-            # request stays queued untouched.
+            # finishing them is what releases blocks).  The oldest
+            # block holder, which eviction protects, is deferred last:
+            # an older stream holding no blocks must not starve it.
+            # The deferred request stays queued untouched.
             needy = [e for e in feeding if demand(e) > 0]
-            entry = max(needy, key=lambda e: self.slot_age[e[0].slot])
+            _, protected = self._block_holders()
+            entry = max(needy, key=lambda e: (e[0].slot != protected,
+                                              self.slot_age[e[0].slot]))
             req = entry[0]
             own = int(alloc.n_blocks_of[req.slot])
             if len(feeding) == 1 and alloc.used_blocks == own:
@@ -676,18 +680,27 @@ class VerificationAwareScheduler:
     def _swap_possible(self, slot: int) -> bool:
         return self.swap is not None and self.swap.plan(slot) is not None
 
+    def _block_holders(self) -> tuple[list[int], int | None]:
+        """Slots holding pool blocks, and the oldest of them (None when
+        there are none).  The oldest holder is protected: eviction and
+        swap never take it and deferral reaches it last, so at least one
+        stream always makes forward progress."""
+        alloc = self.engine.allocator
+        holders = [s for s in range(self.engine.max_slots)
+                   if alloc.n_blocks_of[s] > 0]
+        oldest = (min(holders, key=lambda s: self.slot_age[s])
+                  if holders else None)
+        return holders, oldest
+
     def _pick_victim(self) -> int | None:
         """Block-holding victim by the configured policy (never the
         oldest holder — forward progress).  A candidate must either be
         restartable (recompute-eviction re-derives its partial prefill
         from ``VerifyRequest.seq``) or swappable (the host tier keeps
         its state, no restart needed)."""
-        alloc = self.engine.allocator
-        holders = [s for s in range(self.engine.max_slots)
-                   if alloc.n_blocks_of[s] > 0]
+        holders, oldest = self._block_holders()
         if len(holders) <= 1:
             return None
-        oldest = min(holders, key=lambda s: self.slot_age[s])
         cands = [s for s in holders
                  if s != oldest and (self._slot_restartable(s)
                                      or self._swap_possible(s))]
@@ -707,11 +720,9 @@ class VerificationAwareScheduler:
         busy = {r.slot for r in list(self.active_verify) + list(self.verify_q)}
         freed_any = False
         while deficit > 0:
-            holders = [s for s in range(self.engine.max_slots)
-                       if alloc.n_blocks_of[s] > 0]
+            holders, oldest = self._block_holders()
             if len(holders) <= 1:
                 break
-            oldest = min(holders, key=lambda s: self.slot_age[s])
             cands = [s for s in holders
                      if s != oldest and s not in busy
                      and not self._slot_swapped(s)

@@ -61,12 +61,10 @@ from dataclasses import dataclass
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 from repro.models import model as M
 from repro.serving.trace import NULL_TRACER
-from repro.serving.engine import (BlockPoolExhausted, _CHAIN_ROOT,
-                                  _call_donated)
+from repro.serving.engine import BlockPoolExhausted, _CHAIN_ROOT
 
 PREEMPT_POLICIES = ("youngest", "most-blocks", "slo-aware")
 
@@ -374,7 +372,7 @@ class HostSwapManager:
         if move_bids:
             plan_arr = np.full(a.max_blocks_per_slot, -1, np.int32)
             plan_arr[:len(move_bids)] = move_bids
-            peeked = self._peek(self.engine.cache, jnp.asarray(plan_arr))
+            peeked = self._peek(self.engine.cache, self.engine._put(plan_arr))
             # D2H, then trim the fixed-plan padding: the host keeps only
             # the real blocks (the copy detaches the view so the padded
             # gather buffer is actually freed)
@@ -468,12 +466,11 @@ class HostSwapManager:
             # (one jit specialization); pad rows route out of bounds and
             # never land
             pad = jax.tree.map(
-                lambda x: jnp.asarray(np.pad(
+                lambda x: self.engine._put(np.pad(
                     x, [(0, 0), (0, W - len(scatter_bids))] +
                     [(0, 0)] * (x.ndim - 2))), merged)
-            self.engine.cache = _call_donated(
-                self._scatter, self.engine.cache, jnp.asarray(plan_arr),
-                pad)
+            self.engine.cache = self._scatter(
+                self.engine.cache, self.engine._put(plan_arr), pad)
             self.engine._tables_dirty = True
             moved = len(scatter_bids) * self.engine.block_bytes()
         self.engine._sync_tables()
@@ -537,11 +534,11 @@ class HostSwapManager:
         merged = parts[0] if len(parts) == 1 else jax.tree.map(
             lambda *xs: np.concatenate(xs, axis=1), *parts)
         pad = jax.tree.map(
-            lambda x: jnp.asarray(np.pad(
+            lambda x: self.engine._put(np.pad(
                 x, [(0, 0), (0, W - len(bids))] +
                 [(0, 0)] * (x.ndim - 2))), merged)
-        self.engine.cache = _call_donated(
-            self._scatter, self.engine.cache, jnp.asarray(plan_arr), pad)
+        self.engine.cache = self._scatter(
+            self.engine.cache, self.engine._put(plan_arr), pad)
         self.engine._tables_dirty = True
         for (h, e), b in zip(entries, bids):
             a.register_block(b, h, e.prev, e.tokens)
@@ -593,7 +590,7 @@ class HostSwapManager:
             grp = cand[off:off + W]
             plan_arr = np.full(W, -1, np.int32)
             plan_arr[:len(grp)] = [c[3] for c in grp]
-            peeked = self._peek(self.engine.cache, jnp.asarray(plan_arr))
+            peeked = self._peek(self.engine.cache, self.engine._put(plan_arr))
             peeked = jax.tree.map(
                 lambda x: np.asarray(x)[:, :len(grp)].copy(), peeked)
             for i, (h, prev, blk, _b) in enumerate(grp):

@@ -56,8 +56,11 @@ def _mk_engine(pair, **kw):
 @pytest.fixture(scope="module")
 def fleet4(pair):
     """Four reusable paged prefix-sharing replicas (no retention, so a
-    drained replica returns to pristine pool state between tests)."""
-    return [_mk_engine(pair) for _ in range(4)]
+    drained replica returns to pristine pool state between tests), each
+    committed to a device as ``repro.launch.serve`` places them."""
+    devices = jax.devices()
+    return [_mk_engine(pair, device=devices[i % len(devices)])
+            for i in range(4)]
 
 
 def _prompts(n, length=8, shared=0, seed=5):

@@ -29,7 +29,7 @@ import pytest
 
 import jax
 
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.configs.synera_pair import tiny_pair
 from repro.core.offload import OffloadPolicy
@@ -422,6 +422,9 @@ def test_slo_aware_spares_tight_deadline(pair, dev):
 @given(st.lists(st.integers(4, 20), min_size=2, max_size=4),
        st.integers(0, len(PREEMPT_POLICIES) - 1),
        st.integers(0, 1))            # arrivals: together | staggered
+# an older stream restarting with no blocks once starved the protected
+# block holder into "paged KV pool deadlocked"
+@example(lens=[4, 17, 13], pol_i=0, arr_i=0)
 @settings(max_examples=5, deadline=None)
 def test_streams_identical_across_dispositions(dev, eng_dense, eng_recompute,
                                                eng_swap, lens, pol_i, arr_i):
