@@ -140,6 +140,7 @@ def attn_with_importance(q, k, v, q_pos=None, kv_pos=None, *,
             jax.ShapeDtypeStruct((B * nh, 1, S), jnp.float32),
         ],
         interpret=interpret,
+        name="attn_importance",
     )(qh, kh, vh, qp, kp)
 
     out = out[:, :Tq].reshape(B, nh, Tq, hd)

@@ -122,6 +122,7 @@ def decode_attention(q, k, v, q_pos, kv_pos, *, window: int = 0,
             pltpu.VMEM((g, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_gqa",
     )(qg, kh, vh, qp, kp)
 
     return out.reshape(B, nh, hd)
@@ -290,6 +291,7 @@ def decode_attention_paged(q, k_pool, v_pool, q_pos, pos_pool, block_tables,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_gqa_paged",
     )(bt, qg, *[kh] * fuse, *[vh] * fuse, qp, *[pp] * fuse)
 
     out = PG.combine_splits(m[:, :, 0], l[:, :, 0], acc, q.dtype)

@@ -129,6 +129,7 @@ def partial_prefill_attention(q, k, v, q_pos, kv_pos, *, window: int = 0,
             pltpu.VMEM((C, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="partial_prefill",
     )(qh, kh, vh, qp, kp)
 
     return jnp.moveaxis(out.reshape(B, nh, C, hd), 1, 2)
@@ -284,6 +285,7 @@ def partial_prefill_attention_paged(q, k_pool, v_pool, q_pos, pos_pool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="partial_prefill_paged",
     )(bt, qh, *[kh] * fuse, *[vh] * fuse, qp, *[pp] * fuse)
 
     out = PG.combine_splits(m[:, :, 0], l[:, :, 0], acc, q.dtype)

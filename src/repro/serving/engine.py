@@ -141,8 +141,8 @@ class BlockAllocator:
         # must invalidate before the next write (see take_reclaimed)
         self._reclaim_pending: list[int] = []
         # telemetry
-        # tracing handle (serving/trace.py): installed by the scheduler
-        # when tracing is on; the NULL_TRACER default keeps every
+        # tracing handle (serving/trace.py): installed by the engine's
+        # set_tracer; the NULL_TRACER default keeps every
         # ``if self.tracer.enabled`` guard below allocation-free
         self.tracer = NULL_TRACER
         self.trace_replica = 0
@@ -714,8 +714,19 @@ class CloudEngine:
         self._raw_decode = jax.jit(make_decode_step(cfg, window=window),
                                    donate_argnums=1)
         self._reset = jax.jit(_reset_cache_slot, donate_argnums=0)
-        # telemetry: host transfer + jit specialization accounting
+        # telemetry: host transfer + jit specialization accounting, and
+        # plain counters at the boundaries the host regions mark: rows
+        # each fused sub-chunk dispatched (padded) and carried a token,
+        # per kind; block-table uploads; blocking reads of step results
         self.bytes_to_host = 0
+        self.rows_computed = {"verify": 0, "prefill": 0}
+        self.rows_fed = {"verify": 0, "prefill": 0}
+        self.table_uploads = 0
+        self.table_upload_bytes = 0
+        self.host_syncs = 0
+        # host regions (serving/trace.py); installed by set_tracer
+        self.tracer = NULL_TRACER
+        self.trace_replica = 0
         self._calls = {"feed": 0, "prefill": 0, "decode": 0,
                        "feed_logits": 0, "decode_logits": 0}
         self._specializations: set = set()
@@ -738,6 +749,17 @@ class CloudEngine:
         self.dead = True
 
     # -- telemetry ------------------------------------------------------
+    def set_tracer(self, tracer, replica: int = 0) -> None:
+        """Record this engine's host regions and instants, and its block
+        pool's and swap tier's instants, on ``tracer`` as ``replica``.
+        The engine has one owner, the server that serves it
+        (``SyneraServer`` calls this): a later owner takes its tracer
+        over."""
+        for part in (self, self.allocator, self.swap_manager):
+            if part is not None:
+                part.tracer = tracer
+                part.trace_replica = replica
+
     @property
     def compile_stats(self) -> dict:
         """Which (step, bucket) jit specializations this engine took, and
@@ -805,9 +827,11 @@ class CloudEngine:
         """Push the allocator's block-table mirror into every
         ``block_tables`` cache leaf (host-side leaf swap, no jit)."""
         if self.allocator is not None and self._tables_dirty:
-            self.cache = _set_block_tables(
-                self.cache, self._put(self.allocator.table))
+            table = self.allocator.table
+            self.cache = _set_block_tables(self.cache, self._put(table))
             self._tables_dirty = False
+            self.table_uploads += 1
+            self.table_upload_bytes += table.nbytes
 
     def _ensure_blocks(self, positions: np.ndarray):
         """Grow each active slot's allocation to cover the highest
@@ -823,31 +847,33 @@ class CloudEngine:
                 "may reach it — sessions must be re-placed on a survivor")
         if self.allocator is None:
             return
-        pos = np.asarray(positions)
-        forks: list[tuple[int, int]] = []
-        for slot in range(pos.shape[0]):
-            valid = pos[slot][pos[slot] >= 0]
-            if valid.size == 0:
-                continue
-            if self.allocator.share_prefix:
-                # allocator.s_max (block-size padded) is the same modulus
-                # cache_write_paged wraps with on device
-                idxs = np.unique((valid % self.allocator.s_max)
-                                 // self.allocator.block_size)
-                forks += self.allocator.prepare_writes(slot, idxs)
-            L = min(int(valid.max()) + 1, self.s_max)
-            if self.allocator.needed(slot, L):
-                if not self.allocator.extend(slot, L):
-                    raise BlockPoolExhausted(
-                        f"slot {slot} needs {self.allocator.needed(slot, L)}"
-                        f" more KV blocks; pool has "
-                        f"{self.allocator.free_blocks} free")
+        with self.tracer.region("synera.engine.blocks"):
+            pos = np.asarray(positions)
+            forks: list[tuple[int, int]] = []
+            for slot in range(pos.shape[0]):
+                valid = pos[slot][pos[slot] >= 0]
+                if valid.size == 0:
+                    continue
+                if self.allocator.share_prefix:
+                    # allocator.s_max (block-size padded) is the same
+                    # modulus cache_write_paged wraps with on device
+                    idxs = np.unique((valid % self.allocator.s_max)
+                                     // self.allocator.block_size)
+                    forks += self.allocator.prepare_writes(slot, idxs)
+                L = min(int(valid.max()) + 1, self.s_max)
+                if self.allocator.needed(slot, L):
+                    if not self.allocator.extend(slot, L):
+                        raise BlockPoolExhausted(
+                            f"slot {slot} needs "
+                            f"{self.allocator.needed(slot, L)} more KV "
+                            f"blocks; pool has "
+                            f"{self.allocator.free_blocks} free")
+                    self._tables_dirty = True
+            self._flush_reclaims()
+            if forks:
                 self._tables_dirty = True
-        self._flush_reclaims()
-        if forks:
-            self._tables_dirty = True
-            self._apply_forks(forks)
-        self._sync_tables()
+                self._apply_forks(forks)
+            self._sync_tables()
 
     def _apply_forks(self, pairs: list[tuple[int, int]]):
         """Clone pool content for copy-on-write forks (src -> dst across
@@ -1020,6 +1046,16 @@ class CloudEngine:
                     adopt_in_bytes=sw.adopt_in_bytes if sw else 0,
                     demoted_blocks=sw.demoted_blocks if sw else 0)
 
+    def _specialize(self, step: str, bucket: int) -> None:
+        """Note a (step, bucket) specialization; the first time it is
+        taken, mark it on the trace (a compile, unless cached)."""
+        key = (step, bucket)
+        if key not in self._specializations:
+            self._specializations.add(key)
+            if self.tracer.enabled:
+                self.tracer.instant("specialize", replica=self.trace_replica,
+                                    n=bucket)
+
     # -- bucketing ------------------------------------------------------
     def _bucket_of(self, n: int) -> int:
         for b in self.feed_buckets:
@@ -1042,22 +1078,25 @@ class CloudEngine:
             return arr
         return np.pad(arr, ((0, 0), (0, pad)), constant_values=fill)
 
-    def _run_fused(self, tokens, positions, targets, sel_idx, last_local,
-                   with_dists=True):
-        """One fused sub-chunk; returns lazy (device) outputs.  Callers
-        convert only what they need."""
-        C = tokens.shape[1]
-        Cb = self._bucket_of(C)
-        self._specializations.add(
-            ("fused" if with_dists else "fused_greedy", Cb))
-        step = self._step if with_dists else self._step_greedy
-        out, self.cache = step(
-            self.params, self.cache,
-            self._put(self._pad(tokens, Cb, 0).astype(np.int32)),
-            self._put(self._pad(positions, Cb, -1).astype(np.int32)),
-            self._put(self._pad(targets, Cb, -1).astype(np.int32)),
-            self._put(np.asarray(sel_idx, np.int32)),
-            self._put(np.asarray(last_local, np.int32)))
+    def _run_fused(self, kind, tokens, positions, targets, sel_idx,
+                   last_local, with_dists=True):
+        """One fused sub-chunk of a ``kind`` ("verify" | "prefill")
+        call; returns lazy (device) outputs.  Callers convert only what
+        they need."""
+        with self.tracer.region("synera.engine.dispatch"):
+            B, C = tokens.shape
+            Cb = self._bucket_of(C)
+            self._specialize("fused" if with_dists else "fused_greedy", Cb)
+            self.rows_computed[kind] += B * Cb
+            self.rows_fed[kind] += int((positions >= 0).sum())
+            step = self._step if with_dists else self._step_greedy
+            out, self.cache = step(
+                self.params, self.cache,
+                self._put(self._pad(tokens, Cb, 0).astype(np.int32)),
+                self._put(self._pad(positions, Cb, -1).astype(np.int32)),
+                self._put(self._pad(targets, Cb, -1).astype(np.int32)),
+                self._put(np.asarray(sel_idx, np.int32)),
+                self._put(np.asarray(last_local, np.int32)))
         return out
 
     # ------------------------------------------------------------------
@@ -1075,41 +1114,51 @@ class CloudEngine:
         (iterations whose batched requests are all greedy) selects the
         argmax-only step variant.  Only the fused rows cross to the host.
         """
-        self._calls["feed"] += 1
-        self._ensure_blocks(positions)
-        B, C = tokens.shape
-        R = self.verify_rows_max
-        if targets is None:
-            targets = np.full((B, C), -1, np.int32)
-        if sel_idx is None:
-            sel_idx = np.full((B, R), -1, np.int32)
-        zeros = np.zeros(B, np.int32)
-        tok_acc = np.zeros((B, R), np.int32)
-        p_acc = np.zeros((B, R), np.float32)
-        ki_acc = np.zeros((B, R, self.verify_top_k), np.int32)
-        kv_acc = np.zeros((B, R, self.verify_top_k), np.float32)
-        moved_bytes = 0
-        for off, w in self._chunks(C):
-            sl = slice(off, off + w)
-            in_chunk = (sel_idx >= off) & (sel_idx < off + w)
-            sub_sel = np.where(in_chunk, sel_idx - off, -1).astype(np.int32)
-            res = self._run_fused(tokens[:, sl], positions[:, sl],
-                                  targets[:, sl], sub_sel, zeros,
-                                  with_dists=need_dists)
-            if in_chunk.any():      # only selected rows cross to the host
-                tok = np.asarray(res[0], np.int32)
-                tok_acc = np.where(in_chunk, tok, tok_acc)
-                moved_bytes += tok.nbytes
-                if need_dists:
-                    p_acc = np.where(in_chunk, np.asarray(res[1], np.float32),
-                                     p_acc)
-                    ki_acc = np.where(in_chunk[..., None],
-                                      np.asarray(res[2], np.int32), ki_acc)
-                    kv_acc = np.where(in_chunk[..., None],
-                                      np.asarray(res[3], np.float32), kv_acc)
-                    moved_bytes += (p_acc.nbytes + ki_acc.nbytes
-                                    + kv_acc.nbytes)
-        self.bytes_to_host += moved_bytes
+        tr = self.tracer
+        with tr.region("synera.engine.feed"):
+            self._calls["feed"] += 1
+            self._ensure_blocks(positions)
+            B, C = tokens.shape
+            R = self.verify_rows_max
+            if targets is None:
+                targets = np.full((B, C), -1, np.int32)
+            if sel_idx is None:
+                sel_idx = np.full((B, R), -1, np.int32)
+            zeros = np.zeros(B, np.int32)
+            tok_acc = np.zeros((B, R), np.int32)
+            p_acc = np.zeros((B, R), np.float32)
+            ki_acc = np.zeros((B, R, self.verify_top_k), np.int32)
+            kv_acc = np.zeros((B, R, self.verify_top_k), np.float32)
+            moved_bytes = 0
+            for off, w in self._chunks(C):
+                sl = slice(off, off + w)
+                in_chunk = (sel_idx >= off) & (sel_idx < off + w)
+                sub_sel = np.where(in_chunk, sel_idx - off,
+                                   -1).astype(np.int32)
+                res = self._run_fused("verify", tokens[:, sl],
+                                      positions[:, sl], targets[:, sl],
+                                      sub_sel, zeros, with_dists=need_dists)
+                if not in_chunk.any():
+                    continue
+                # only selected rows cross to the host
+                with tr.region("synera.engine.sync"):
+                    self.host_syncs += 1
+                    tok = np.asarray(res[0], np.int32)
+                    tok_acc = np.where(in_chunk, tok, tok_acc)
+                    moved_bytes += tok.nbytes
+                    if need_dists:
+                        p_acc = np.where(in_chunk,
+                                         np.asarray(res[1], np.float32),
+                                         p_acc)
+                        ki_acc = np.where(in_chunk[..., None],
+                                          np.asarray(res[2], np.int32),
+                                          ki_acc)
+                        kv_acc = np.where(in_chunk[..., None],
+                                          np.asarray(res[3], np.float32),
+                                          kv_acc)
+                        moved_bytes += (p_acc.nbytes + ki_acc.nbytes
+                                        + kv_acc.nbytes)
+            self.bytes_to_host += moved_bytes
         return VerifyRows(token_id=tok_acc, p_draft=p_acc,
                           topk_idx=ki_acc, topk_val=kv_acc)
 
@@ -1118,40 +1167,47 @@ class CloudEngine:
         row's full logits (max_slots, V) — gathered on device, one
         vocab-row per slot — and writes the cache.  Slots with no valid
         positions return zeros."""
-        self._calls["prefill"] += 1
-        self._ensure_blocks(positions)
-        B, C = tokens.shape
-        valid = positions >= 0
-        # last valid column per slot (-1 = idle).  Valid entries need
-        # not start at column 0: prefix-sharing feeds align columns with
-        # absolute positions and pad the shared prefix
-        last_col = np.where(valid.any(axis=1),
-                            C - 1 - np.argmax(valid[:, ::-1], axis=1), -1)
-        targets = np.full((B, C), -1, np.int32)
-        no_sel = np.full((B, self.verify_rows_max), -1, np.int32)
-        out = np.zeros((B, self.vocab), np.float32)
-        for off, w in self._chunks(C):
-            sl = slice(off, off + w)
-            if not (positions[:, sl] >= 0).any():
-                continue   # every slot's columns are shared-prefix padding
-            local = np.clip(last_col - off, 0, w - 1).astype(np.int32)
-            # only the last-row gather is consumed: the argmax-only step
-            # variant suffices (no extra specialization, no wasted top-k)
-            res = self._run_fused(tokens[:, sl], positions[:, sl],
-                                  targets[:, sl], no_sel, local,
-                                  with_dists=False)
-            sel = (last_col >= off) & (last_col < off + w)
-            if sel.any():
+        tr = self.tracer
+        with tr.region("synera.engine.prefill"):
+            self._calls["prefill"] += 1
+            self._ensure_blocks(positions)
+            B, C = tokens.shape
+            valid = positions >= 0
+            # last valid column per slot (-1 = idle).  Valid entries need
+            # not start at column 0: prefix-sharing feeds align columns
+            # with absolute positions and pad the shared prefix
+            last_col = np.where(valid.any(axis=1),
+                                C - 1 - np.argmax(valid[:, ::-1], axis=1),
+                                -1)
+            targets = np.full((B, C), -1, np.int32)
+            no_sel = np.full((B, self.verify_rows_max), -1, np.int32)
+            out = np.zeros((B, self.vocab), np.float32)
+            for off, w in self._chunks(C):
+                sl = slice(off, off + w)
+                if not (positions[:, sl] >= 0).any():
+                    continue   # every slot's columns are shared padding
+                local = np.clip(last_col - off, 0, w - 1).astype(np.int32)
+                # only the last-row gather is consumed: the argmax-only
+                # step variant suffices (no extra specialization, no
+                # wasted top-k)
+                res = self._run_fused("prefill", tokens[:, sl],
+                                      positions[:, sl], targets[:, sl],
+                                      no_sel, local, with_dists=False)
+                sel = (last_col >= off) & (last_col < off + w)
+                if not sel.any():
+                    continue
                 # gather on device only the slots whose LAST prompt row
                 # lives in this sub-chunk — the documented transfer is
                 # one vocab row per prefilled slot, not (slots, V) per
                 # sub-chunk
-                idx = np.where(sel)[0]
-                rows = np.asarray(
-                    jnp.take(res[4], self._put(idx.astype(np.int32)), axis=0),
-                    np.float32)
-                out[idx] = rows
-                self.bytes_to_host += rows.nbytes
+                with tr.region("synera.engine.sync"):
+                    self.host_syncs += 1
+                    idx = np.where(sel)[0]
+                    rows = np.asarray(
+                        jnp.take(res[4], self._put(idx.astype(np.int32)),
+                                 axis=0), np.float32)
+                    out[idx] = rows
+                    self.bytes_to_host += rows.nbytes
         return out
 
     def decode(self, tokens: np.ndarray, positions: np.ndarray) -> DecodeRows:
@@ -1160,14 +1216,16 @@ class CloudEngine:
         Returns fused last-token rows (argmax + top-k support)."""
         self._calls["decode"] += 1
         self._ensure_blocks(positions)
-        self._specializations.add(("decode", 1))
+        self._specialize("decode", 1)
         (tok, tk_i, tk_v), self.cache = self._decode(
             self.params, self.cache,
             self._put(np.asarray(tokens, np.int32)),
             self._put(np.asarray(positions, np.int32)))
-        rows = DecodeRows(token_id=np.asarray(tok, np.int32),
-                          topk_idx=np.asarray(tk_i, np.int32),
-                          topk_val=np.asarray(tk_v, np.float32))
+        with self.tracer.region("synera.engine.sync"):
+            self.host_syncs += 1
+            rows = DecodeRows(token_id=np.asarray(tok, np.int32),
+                              topk_idx=np.asarray(tk_i, np.int32),
+                              topk_val=np.asarray(tk_v, np.float32))
         self.bytes_to_host += rows.nbytes
         return rows
 
@@ -1182,7 +1240,7 @@ class CloudEngine:
         for off, w in self._chunks(tokens.shape[1]):
             sl = slice(off, off + w)
             Cb = self._bucket_of(w)
-            self._specializations.add(("raw", Cb))
+            self._specialize("raw", Cb)
             logits, self.cache = self._raw_verify(
                 self.params, self.cache,
                 self._put(self._pad(tokens[:, sl], Cb, 0).astype(np.int32)),
@@ -1198,7 +1256,7 @@ class CloudEngine:
         """Pre-fusion decode: full last-token logits (max_slots, V)."""
         self._calls["decode_logits"] += 1
         self._ensure_blocks(positions)
-        self._specializations.add(("raw_decode", 1))
+        self._specialize("raw_decode", 1)
         logits, self.cache = self._raw_decode(
             self.params, self.cache,
             self._put(np.asarray(tokens, np.int32)),

@@ -127,15 +127,9 @@ class VerificationAwareScheduler:
         # NULL_TRACER default keeps the disabled path allocation-free
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.replica = replica
-        if self.tracer.enabled:
-            alloc = getattr(engine, "allocator", None)
-            if alloc is not None:
-                alloc.tracer = self.tracer
-                alloc.trace_replica = replica
-            swp = getattr(engine, "swap_manager", None)
-            if swp is not None:
-                swp.tracer = self.tracer
-                swp.trace_replica = replica
+        # (kind, request ids) of the last iteration that fed, kept while
+        # tracing; SyneraServer.step clears it before each step
+        self.traced_batch = None
         policy = (preempt_policy
                   or getattr(getattr(engine, "cfg", None),
                              "preempt_policy", None)
@@ -177,6 +171,9 @@ class VerificationAwareScheduler:
         self.preempted_refed_tokens = 0
         self.admission_swaps = 0    # proactive swap-outs at admission
         self.prefill_fed_tokens = 0  # cumulative prompt tokens actually fed
+        # cumulative tokens of the prompts whose prefill completed; with
+        # prefill_fed_tokens, the share the prefix cache served
+        self.prompt_tokens_done = 0
         # consecutive verify iterations that deferred EVERY chunk with
         # nothing evicted and nothing else executing — a growing streak
         # means no stream can ever free blocks (all holders
@@ -296,11 +293,19 @@ class VerificationAwareScheduler:
         Returns completion events.  If no queued request has arrived yet
         (shared-clock semantics), fast-forwards the clock to the next
         arrival and returns [] — callers loop while ``has_work()``.
+
+        Host regions: ``synera.sched.plan`` covers admission, batch
+        assembly and block reservation up to the engine call, and
+        ``synera.sched.accept`` what follows it (the clock charge, rows
+        handed to their requests, the verifier's acceptance).
         """
-        self._swap_in_ready()
-        now = self.clock.now_ms
-        if self.prefill_q and self.free_slots and \
-                any(r.arrival_ms <= now for r in self.prefill_q):
+        tr = self.tracer
+        with tr.region("synera.sched.plan"):
+            self._swap_in_ready()
+            now = self.clock.now_ms
+            prefill = bool(self.prefill_q and self.free_slots and any(
+                r.arrival_ms <= now for r in self.prefill_q))
+        if prefill:
             evs = self._prefill_iteration(now)
             if evs:
                 self.iterations += 1
@@ -315,16 +320,17 @@ class VerificationAwareScheduler:
         # (e.g. a prefill with no free slot) must not pin the clock —
         # unblocking them needs an external action (slot release), not
         # time.
-        future = [a for a in
-                  ([r.arrival_ms for r in self.prefill_q]
-                   + [r.arrival_ms for r in self.verify_q])
-                  if a > now]
-        if future:
-            t0 = self.clock.now_ms
-            self.clock.advance_to(min(future))
-            if self.tracer.enabled and self.clock.now_ms > t0:
-                self.tracer.span(t0, self.clock.now_ms, "idle",
-                                 replica=self.replica)
+        with tr.region("synera.sched.plan"):
+            future = [a for a in
+                      ([r.arrival_ms for r in self.prefill_q]
+                       + [r.arrival_ms for r in self.verify_q])
+                      if a > now]
+            if future:
+                t0 = self.clock.now_ms
+                self.clock.advance_to(min(future))
+                if tr.enabled and self.clock.now_ms > t0:
+                    tr.span(t0, self.clock.now_ms, "idle",
+                            replica=self.replica)
         return []
 
     # -- prefill (lines 5-11) ------------------------------------------
@@ -339,6 +345,72 @@ class VerificationAwareScheduler:
         return self.swap.blocks_needed(slots[0]) if slots else 0
 
     def _prefill_iteration(self, now: float) -> list[SchedulerEvent]:
+        tr = self.tracer
+        with tr.region("synera.sched.plan"):
+            batch = self._admit_prefills(now)
+            if not batch:
+                return []  # wait for a free slot
+            self._defer_streak = 0         # admission is forward progress
+            B = self.engine.max_slots
+            C = max(len(r.tokens) for r in batch)
+            tokens = np.zeros((B, C), np.int32)
+            positions = np.full((B, C), -1, np.int32)
+            for r in batch:
+                T, m = len(r.tokens), r.shared
+                # columns align with absolute positions; a shared prefix
+                # is leading padding.  This is what keeps same-batch
+                # adoption safe when the bucket ladder splits a wide
+                # prompt batch into sequential sub-chunks: sub-chunk k
+                # scatters position range k for EVERY slot before any
+                # later sub-chunk's rows attend over it, so an adopter's
+                # suffix never reads prefix positions its filler has not
+                # yet written
+                tokens[r.slot, m:T] = r.tokens[m:]
+                positions[r.slot, m:T] = np.arange(m, T)
+        # one full-vocab row per slot crosses to the host here (the
+        # sampling verifier's pre-draft row); verify iterations never
+        # transfer a vocab-sized tensor
+        t_exec0 = self.clock.now_ms
+        b0 = getattr(self.engine, "bytes_to_host", 0)
+        last_rows = self.engine.prefill(tokens, positions)
+        moved = getattr(self.engine, "bytes_to_host", 0) - b0
+
+        with tr.region("synera.sched.accept"):
+            events = []
+            # shared prefix tokens are cache hits: neither fed nor
+            # charged; blocks adopted from the content-addressed host
+            # store are charged as H2D transfers instead (take_uncharged)
+            total = sum(len(r.tokens) - r.shared for r in batch)
+            self.prefill_fed_tokens += total
+            self.prompt_tokens_done += sum(len(r.tokens) for r in batch)
+            adopted = (self.swap.take_uncharged() if self.swap is not None
+                       else 0)
+            self.clock.advance(self.latency.prefill_ms(total)
+                               + self.latency.host_transfer_ms(moved
+                                                               + adopted))
+            self.prefill_iterations += 1
+            if tr.enabled:
+                rids = tuple(r.req_id for r in batch)
+                self.traced_batch = ("prefill", rids)
+                tr.span(t_exec0, self.clock.now_ms, "prefill",
+                        replica=self.replica, rids=rids, tokens=total,
+                        nbytes=moved + adopted)
+                for r in batch:
+                    tr.queue(r.req_id, "prefill", r.arrival_ms, t_exec0,
+                             self.replica)
+            for r in batch:
+                T = len(r.tokens)
+                self.cloud_len[r.slot] = T
+                self.last_row[r.slot] = last_rows[r.slot]
+                events.append(SchedulerEvent(
+                    "prefill_done", r.req_id, r.slot,
+                    last_logits=last_rows[r.slot]))
+        return events
+
+    def _admit_prefills(self, now: float) -> list[PrefillRequest]:
+        """Admit arrived prompt prefills (FCFS) while a slot and enough
+        blocks are free; returns the admitted batch, slots assigned and
+        blocks allocated."""
         alloc = getattr(self.engine, "allocator", None)
         blocks_exhausted = False
         batch: list[PrefillRequest] = []
@@ -411,61 +483,74 @@ class VerificationAwareScheduler:
                                                       bids=matched)
             batch.append(req)
         self.prefill_q = rest
-        if not batch:
-            return []  # wait for a free slot
-        self._defer_streak = 0         # admission is forward progress
-
-        B = self.engine.max_slots
-        C = max(len(r.tokens) for r in batch)
-        tokens = np.zeros((B, C), np.int32)
-        positions = np.full((B, C), -1, np.int32)
-        for r in batch:
-            T, m = len(r.tokens), r.shared
-            # columns align with absolute positions; a shared prefix is
-            # leading padding.  This is what keeps same-batch adoption
-            # safe when the bucket ladder splits a wide prompt batch
-            # into sequential sub-chunks: sub-chunk k scatters position
-            # range k for EVERY slot before any later sub-chunk's rows
-            # attend over it, so an adopter's suffix never reads prefix
-            # positions its filler has not yet written
-            tokens[r.slot, m:T] = r.tokens[m:]
-            positions[r.slot, m:T] = np.arange(m, T)
-        # one full-vocab row per slot crosses to the host here (the
-        # sampling verifier's pre-draft row); verify iterations never
-        # transfer a vocab-sized tensor
-        t_exec0 = self.clock.now_ms
-        b0 = getattr(self.engine, "bytes_to_host", 0)
-        last_rows = self.engine.prefill(tokens, positions)
-        moved = getattr(self.engine, "bytes_to_host", 0) - b0
-
-        events = []
-        # shared prefix tokens are cache hits: neither fed nor charged;
-        # blocks adopted from the content-addressed host store are
-        # charged as H2D transfers instead (take_uncharged)
-        total = sum(len(r.tokens) - r.shared for r in batch)
-        self.prefill_fed_tokens += total
-        adopted = self.swap.take_uncharged() if self.swap is not None else 0
-        self.clock.advance(self.latency.prefill_ms(total)
-                           + self.latency.host_transfer_ms(moved + adopted))
-        self.prefill_iterations += 1
-        if self.tracer.enabled:
-            self.tracer.span(t_exec0, self.clock.now_ms, "prefill",
-                             replica=self.replica,
-                             rids=tuple(r.req_id for r in batch),
-                             tokens=total, nbytes=moved + adopted)
-        for r in batch:
-            T = len(r.tokens)
-            self.cloud_len[r.slot] = T
-            self.last_row[r.slot] = last_rows[r.slot]
-            events.append(SchedulerEvent(
-                "prefill_done", r.req_id, r.slot,
-                last_logits=last_rows[r.slot]))
-        return events
+        return batch
 
     # -- verification partial prefill (lines 12-21) ---------------------
     def _verify_iteration(self, now: float) -> list[SchedulerEvent] | None:
         """Returns events for the executed batch, or None if no verify
         chunk was admissible at ``now`` (caller decides how to wait)."""
+        tr = self.tracer
+        with tr.region("synera.sched.plan"):
+            batch = self._plan_verify(now)
+        if batch is None:
+            return None
+        feeding, tokens, positions, targets, sel_idx, kept = batch
+        t_exec0 = self.clock.now_ms
+        b0 = getattr(self.engine, "bytes_to_host", 0)
+        if self.fused:
+            need_dists = any(r.sampling != "greedy" for r, _, _ in feeding)
+            rows = self.engine.feed(tokens, positions, targets, sel_idx,
+                                    need_dists=need_dists)
+        else:
+            logits = self.engine.feed_logits(tokens, positions)
+        moved = getattr(self.engine, "bytes_to_host", 0) - b0
+        with tr.region("synera.sched.accept"):
+            total = sum(n for _, _, n in feeding)
+            self.clock.advance(self.latency.iteration_ms(total)
+                               + self.latency.host_transfer_ms(moved))
+            self.verify_iterations += 1
+            self.verify_occupancy.append(len(feeding))
+            self.verify_tokens_fed.append(total)
+            if tr.enabled:
+                rids = tuple(r.req_id for r, _, _ in feeding)
+                self.traced_batch = ("verify", rids)
+                tr.span(t_exec0, self.clock.now_ms, "verify",
+                        replica=self.replica, rids=rids, tokens=total,
+                        nbytes=moved)
+                for r, _, _ in feeding:
+                    tr.queue(r.req_id, "verify", r.arrival_ms, t_exec0,
+                             self.replica)
+            events = []
+            for req, fed0, n in feeding:
+                gamma = len(req.draft)
+                seq_len = len(req.uncached) + gamma
+                if self.fused:
+                    for r, i in enumerate(kept[req.slot]):
+                        req.rows.append((req.start_pos + fed0 + i, (
+                            int(rows.token_id[req.slot, r]),
+                            float(rows.p_draft[req.slot, r]),
+                            rows.topk_idx[req.slot, r],
+                            rows.topk_val[req.slot, r])))
+                else:
+                    keep_from = seq_len - gamma - 1
+                    for i in range(n):
+                        idx = fed0 + i
+                        if idx >= keep_from:
+                            req.rows.append((req.start_pos + idx,
+                                             logits[req.slot, i]))
+                req.fed = fed0 + n
+                self.cloud_len[req.slot] = req.start_pos + req.fed
+                if req.fed >= seq_len:
+                    events.append(self._finish_verify(req))
+            self.active_verify = [r for r in self.active_verify
+                                  if r.fed < len(r.uncached) + len(r.draft)]
+        return events
+
+    def _plan_verify(self, now: float):
+        """Pack at most one chunk per slot from the arrived verify
+        requests and reserve their blocks.  Returns ``(feeding, tokens,
+        positions, targets, sel_idx, kept)``, or None when no chunk can
+        run at ``now``."""
         still: deque[VerifyRequest] = deque()
         while self.verify_q:
             r = self.verify_q.popleft()
@@ -525,52 +610,7 @@ class VerificationAwareScheduler:
                 self.tracer.span(t0, self.clock.now_ms, "sched",
                                  replica=self.replica)
             return None
-        t_exec0 = self.clock.now_ms
-        b0 = getattr(self.engine, "bytes_to_host", 0)
-        if self.fused:
-            need_dists = any(r.sampling != "greedy" for r, _, _ in feeding)
-            rows = self.engine.feed(tokens, positions, targets, sel_idx,
-                                    need_dists=need_dists)
-        else:
-            logits = self.engine.feed_logits(tokens, positions)
-        moved = getattr(self.engine, "bytes_to_host", 0) - b0
-        total = sum(n for _, _, n in feeding)
-        self.clock.advance(self.latency.iteration_ms(total)
-                           + self.latency.host_transfer_ms(moved))
-        self.verify_iterations += 1
-        self.verify_occupancy.append(len(feeding))
-        self.verify_tokens_fed.append(total)
-        if self.tracer.enabled:
-            self.tracer.span(t_exec0, self.clock.now_ms, "verify",
-                             replica=self.replica,
-                             rids=tuple(r.req_id for r, _, _ in feeding),
-                             tokens=total, nbytes=moved)
-
-        events = []
-        for req, fed0, n in feeding:
-            gamma = len(req.draft)
-            seq_len = len(req.uncached) + gamma
-            if self.fused:
-                for r, i in enumerate(kept[req.slot]):
-                    req.rows.append((req.start_pos + fed0 + i, (
-                        int(rows.token_id[req.slot, r]),
-                        float(rows.p_draft[req.slot, r]),
-                        rows.topk_idx[req.slot, r],
-                        rows.topk_val[req.slot, r])))
-            else:
-                keep_from = seq_len - gamma - 1
-                for i in range(n):
-                    idx = fed0 + i
-                    if idx >= keep_from:
-                        req.rows.append((req.start_pos + idx,
-                                         logits[req.slot, i]))
-            req.fed = fed0 + n
-            self.cloud_len[req.slot] = req.start_pos + req.fed
-            if req.fed >= seq_len:
-                events.append(self._finish_verify(req))
-        self.active_verify = [r for r in self.active_verify
-                              if r.fed < len(r.uncached) + len(r.draft)]
-        return events
+        return feeding, tokens, positions, targets, sel_idx, kept
 
     # -- paged-pool admission + preemption ------------------------------
     def _reserve_blocks(self, feeding, tokens, positions, targets,

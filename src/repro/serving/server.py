@@ -124,6 +124,13 @@ class ServerStats:
     demoted_blocks: int = 0            # blocks demoted to host on release
     admission_swaps: int = 0           # idle streams swapped to admit prompts
     prefill_fed_tokens: int = 0        # cumulative tokens fed through prefill
+    prompt_tokens_done: int = 0        # tokens of prompts whose prefill ran
+    # -- host boundaries (engine; docs/observability.md "On the chip") --
+    rows_computed: int = 0             # rows the verify/prefill steps ran
+    rows_fed: int = 0                  # of those, rows carrying a token
+    table_uploads: int = 0             # block-table uploads to the device
+    table_upload_bytes: int = 0        # their bytes
+    host_syncs: int = 0                # host reads blocked on step results
     # -- request lifecycle (gateway front door, serving/gateway/) --
     clock: str = "sim"                 # "sim" (SimClock) | "wall" (RealClock)
     modeled_ms: float = 0.0            # shadow modeled time (== sim_ms on sim)
@@ -216,6 +223,7 @@ class SyneraServer:
         self.replica = replica
         if self.tracer.enabled and self.tracer.clock is not self.clock:
             raise ValueError("tracer and server must share one clock")
+        engine.set_tracer(self.tracer, replica)
         self.sched = VerificationAwareScheduler(
             engine, chunk=chunk, latency=latency, clock=self.clock,
             preempt_policy=preempt_policy, tracer=self.tracer,
@@ -458,63 +466,89 @@ class SyneraServer:
     def step(self) -> bool:
         """One event-loop step: drain runnable sessions, then execute one
         scheduler iteration and deliver its completions.  Returns False
-        once every session is done."""
+        once every session is done.
+
+        Host regions: the step is ``synera.step`` (its args: the kind of
+        iteration it ran and the request ids fed); inside it,
+        ``synera.server.open`` advances freshly opened sessions and
+        ``synera.server.deliver`` hands the iteration's completions back
+        to their streams and runs them to their next cloud call."""
+        tr = self.tracer
+        self.sched.traced_batch = None     # a step may run no iteration
+        with tr.region("synera.step", self.replica) as region:
+            more = self._step()
+            if tr.enabled and self.sched.traced_batch is not None:
+                kind, rids = self.sched.traced_batch
+                region.args.update(kind=kind, rids=rids)
+        return more
+
+    def _step(self) -> bool:
+        tr = self.tracer
         # Only freshly opened sessions start in `running`; every other
         # transition back to `running` is advanced inline when its event
         # is delivered below, so no full-session scan is needed.
         progressed = bool(self._fresh)
-        while self._fresh:
-            self._advance(self._fresh.popleft(), None)
+        with tr.region("synera.server.open"):
+            while self._fresh:
+                self._advance(self._fresh.popleft(), None)
         if self._done_count == len(self.sessions):
             return False
 
         t_before = self.clock.now_ms
         events = self.sched.run_iteration()
-        for ev in events:
-            entry = self._by_req.pop(ev.req_id, None)
-            if entry is None:
-                continue
-            s, kind = entry
-            s.client.on_event(ev)
-            if kind == "prefill":
-                s.slots_used.append(ev.slot)
-                if self.tracer.enabled and s.trace_uid >= 0:
-                    self.tracer.stream_instant(s.trace_uid, "slot_assigned",
-                                               self.clock.now_ms, n=ev.slot)
-                if s.done:
-                    # the stream finished before its prefill executed
-                    # (cancellation raced the iteration): free the slot
-                    s.client.release()
-                elif s.pending_call is not None:
-                    call, s.pending_call = s.pending_call, None
-                    self._submit_verify(s, call)
-            else:
-                now = self.clock.now_ms
-                cloud_ms = now - s.arrival_abs_ms
-                cloud_parts = None
-                if self.tracer.enabled:
-                    # decompose the request's in-flight window for the
-                    # device coroutine's stall attribution, and stamp
-                    # the round trip on the stream's async track
-                    cloud_parts = self.tracer.window_parts(
-                        s.arrival_abs_ms, now, replica=self.replica,
-                        slot=ev.slot, vrid=ev.req_id,
-                        prefill_rid=s.prefill_rid)
-                    if s.trace_uid >= 0:
-                        self.tracer.stream_child(
-                            s.trace_uid, "verify_rt",
-                            min(s.trace_send_ms, now), now)
-                reply = CloudReply(result=ev.result, cloud_ms=cloud_ms,
-                                   fed_tokens=s.client.last_fed_tokens,
-                                   cloud_parts=cloud_parts)
-                s.state = RUNNING
-                self._advance(s, reply)
+        with tr.region("synera.server.deliver"):
+            for ev in events:
+                self._deliver(ev)
         if (not events and not progressed
                 and self.clock.now_ms == t_before):
             raise RuntimeError(
                 "SyneraServer stalled: sessions waiting but no scheduler "
                 "event fired and the clock cannot advance")
         return self._done_count < len(self.sessions)
+
+    def _deliver(self, ev) -> None:
+        """Hand one scheduler completion to its stream."""
+        entry = self._by_req.pop(ev.req_id, None)
+        if entry is None:
+            return
+        s, kind = entry
+        s.client.on_event(ev)
+        tr = self.tracer
+        if tr.enabled and s.trace_uid >= 0:
+            q = tr.queued(ev.req_id, self.replica)
+            if q is not None:
+                tr.stream_child(s.trace_uid, "queued", q[1], q[2])
+        if kind == "prefill":
+            s.slots_used.append(ev.slot)
+            if tr.enabled and s.trace_uid >= 0:
+                tr.stream_instant(s.trace_uid, "slot_assigned",
+                                  self.clock.now_ms, n=ev.slot)
+            if s.done:
+                # the stream finished before its prefill executed
+                # (cancellation raced the iteration): free the slot
+                s.client.release()
+            elif s.pending_call is not None:
+                call, s.pending_call = s.pending_call, None
+                self._submit_verify(s, call)
+            return
+        now = self.clock.now_ms
+        cloud_ms = now - s.arrival_abs_ms
+        cloud_parts = None
+        if tr.enabled:
+            # decompose the request's in-flight window for the device
+            # coroutine's stall attribution, and stamp the round trip on
+            # the stream's async track
+            cloud_parts = tr.window_parts(
+                s.arrival_abs_ms, now, replica=self.replica, slot=ev.slot,
+                vrid=ev.req_id, prefill_rid=s.prefill_rid)
+            if s.trace_uid >= 0:
+                tr.stream_child(s.trace_uid, "verify_rt",
+                                min(s.trace_send_ms, now), now)
+        reply = CloudReply(result=ev.result, cloud_ms=cloud_ms,
+                           fed_tokens=s.client.last_fed_tokens,
+                           cloud_parts=cloud_parts)
+        s.state = RUNNING
+        self._advance(s, reply)
 
     def run(self) -> list:
         """Drive all open sessions to completion; returns their metrics
@@ -562,7 +596,8 @@ class SyneraServer:
         sched = self.sched
         occ = sched.verify_occupancy
         toks = sched.verify_tokens_fed
-        pool = self.engine.pool_stats
+        eng = self.engine
+        pool = eng.pool_stats
         # one count per stream without a slot: sessions parked in
         # wait_slot and owners of still-queued prompt prefills overlap
         # (the queued prefill is what wait_slot waits on)
@@ -654,6 +689,12 @@ class SyneraServer:
             demoted_blocks=pool["demoted_blocks"],
             admission_swaps=sched.admission_swaps,
             prefill_fed_tokens=sched.prefill_fed_tokens,
+            prompt_tokens_done=sched.prompt_tokens_done,
+            rows_computed=sum(eng.rows_computed.values()),
+            rows_fed=sum(eng.rows_fed.values()),
+            table_uploads=eng.table_uploads,
+            table_upload_bytes=eng.table_upload_bytes,
+            host_syncs=eng.host_syncs,
         )
 
     def stats(self) -> dict:

@@ -170,8 +170,8 @@ class HostSwapManager:
         # stays live (retention) or is invalidated separately (release)
         self._peek = jax.jit(M.peek_cache_blocks)
         self._scatter = jax.jit(M.swap_in_blocks, donate_argnums=0)
-        # tracing handle (serving/trace.py): installed by the scheduler
-        # when tracing is on; NULL_TRACER keeps the guards free
+        # tracing handle (serving/trace.py): installed by the engine's
+        # set_tracer; NULL_TRACER keeps the guards free
         self.tracer = NULL_TRACER
         self.trace_replica = 0
         # telemetry (cumulative; pool_stats / ServerStats)

@@ -53,10 +53,22 @@ tracing on or off.  When disabled, the module-level :data:`NULL_TRACER`
 is installed everywhere and every hot-path call site guards on
 ``tracer.enabled`` — the disabled path allocates nothing.
 
+* **Host regions** — nested ``synera.*`` regions (one event-loop step,
+  the scheduler's planning, the engine's block growth, dispatch and
+  host syncs, the server's delivery) on the run's clock, kept apart
+  from the charge spans so the attribution above is untouched.  Each
+  region also opens a ``jax.profiler.TraceAnnotation`` of its name, so
+  under a profiler session it lands on the host plane of the device
+  trace, on that trace's clock.
+
+* **Queue records** — each request's arrival and the start of the
+  iteration that first fed it (``queued`` on its stream's track).
+
 Export is Chrome trace-event JSON (``{"traceEvents": [...]}``) loadable
-in ``ui.perfetto.dev``: one process per replica with an engine track
-plus one track per touched slot, and a ``streams`` process carrying the
-per-stream async spans.  See docs/observability.md.
+in ``ui.perfetto.dev``: one process per replica with an engine track,
+a ``host`` track of nested regions and one track per touched slot, and
+a ``streams`` process carrying the per-stream async spans.  See
+docs/observability.md.
 """
 from __future__ import annotations
 
@@ -66,6 +78,10 @@ from dataclasses import dataclass
 
 # engine-side span kinds whose cost is a host-swap transfer for a slot
 _SWAP_KINDS = ("swap_out", "swap_in", "swap_demote")
+
+# thread of the host regions inside a replica's process (the engine
+# track is tid 0, slot tracks 1 + slot)
+HOST_TID = 1 << 16
 
 # fixed Prometheus histogram ladder for TTFT/TPOT/E2E (milliseconds)
 HIST_BUCKETS_MS = (5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0,
@@ -206,6 +222,21 @@ class StreamTimeline:
 # Tracer
 # ---------------------------------------------------------------------------
 
+class _NullRegion:
+    """The one context every disabled region site enters: nothing is
+    recorded and nothing is allocated."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return None
+
+
+_NULL_REGION = _NullRegion()
+
+
 class _NullTracer:
     """Disabled tracer: every method is a no-op and ``enabled`` is
     False, so hot paths guard with one attribute read and never build
@@ -218,6 +249,15 @@ class _NullTracer:
 
     def span(self, *a, **k):
         pass
+
+    def region(self, name, replica=None):
+        return _NULL_REGION
+
+    def queue(self, *a, **k):
+        pass
+
+    def queued(self, *a, **k):
+        return None
 
     def instant(self, *a, **k):
         pass
@@ -263,6 +303,47 @@ class _StreamRec:
         self.end_meta = None
 
 
+class _Region:
+    """One host region: records ``[name, t0, t1, parent, replica, args]``
+    in ``Tracer._regions`` and holds a profiler annotation of the same
+    name while open.  ``args`` may be filled in before the region
+    closes."""
+    __slots__ = ("tracer", "name", "replica", "args", "_i", "_ann")
+
+    def __init__(self, tracer, name, replica, args):
+        self.tracer = tracer
+        self.name = name
+        self.replica = replica
+        self.args = args
+        self._i = -1
+        self._ann = None
+
+    def __enter__(self):
+        tr = self.tracer
+        self._ann = tr._annotation(self.name)
+        self._ann.__enter__()
+        parent = tr._open[-1] if tr._open else -1
+        rep = self.replica
+        if rep is None:
+            rep = tr._regions[parent][4] if parent >= 0 else 0
+        if len(tr._regions) < tr.max_records:
+            self._i = len(tr._regions)
+            tr._regions.append([self.name, tr.clock.now_ms, None, parent,
+                                rep, self.args])
+            tr._open.append(self._i)
+        else:
+            tr.dropped += 1
+        return self
+
+    def __exit__(self, *exc):
+        tr = self.tracer
+        if self._i >= 0:
+            tr._regions[self._i][2] = tr.clock.now_ms
+            tr._open.pop()
+        self._ann.__exit__(*exc)
+        return None
+
+
 class Tracer:
     """Records spans/instants stamped on the shared clock.
 
@@ -286,6 +367,13 @@ class Tracer:
         self._rewinds = []        # (t, replica, rids) — preemption rewinds
         self._streams: dict[int, _StreamRec] = {}
         self._uid = 0
+        # host regions: [name, t0, t1, parent index, replica, args]
+        self._regions = []
+        self._open = []           # indices of the regions open now
+        # (replica, req_id) -> (kind, arrival_ms, dispatch_ms)
+        self._queue = {}
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
 
     # -- engine-side recording -----------------------------------------
     def span(self, t0: float, t1: float, kind: str, replica: int = 0,
@@ -308,6 +396,47 @@ class Tracer:
             self.dropped += 1
             return
         self._instants.append((t, kind, replica, slot, rids, n))
+
+    # -- host regions and queue records ---------------------------------
+    def region(self, name: str, replica: int | None = None,
+               **args) -> _Region:
+        """Context manager: a host region ``name`` on the run's clock,
+        nested in whatever region is open (``replica`` defaults to the
+        enclosing region's, else 0), and a ``TraceAnnotation`` of the
+        same name for a profiler session to pick up.  Call sites that
+        also run untraced pass no ``args`` (the disabled region takes
+        none, so it allocates nothing) and fill ``handle.args`` under
+        ``tracer.enabled`` instead."""
+        return _Region(self, name, replica, args)
+
+    def regions(self) -> list[tuple]:
+        """Regions in the order opened, as ``(name, t0, t1, parent,
+        replica, args)``: ``parent`` indexes this list (-1 at the top),
+        ``t1`` is None while the region is open."""
+        return [tuple(r) for r in self._regions]
+
+    def queue(self, req_id: int, kind: str, arrival_ms: float,
+              dispatch_ms: float, replica: int = 0) -> None:
+        """Record when request ``req_id`` arrived and when the iteration
+        that first fed it began; later dispatches of it keep the
+        first."""
+        key = (replica, req_id)
+        if key in self._queue:
+            return
+        if len(self._queue) >= self.max_records:
+            self.dropped += 1
+            return
+        self._queue[key] = (kind, arrival_ms, dispatch_ms)
+
+    def queued(self, req_id: int, replica: int = 0):
+        """``(kind, arrival_ms, dispatch_ms)`` of a recorded request, or
+        None."""
+        return self._queue.get((replica, req_id))
+
+    def queue_records(self) -> list[tuple]:
+        """Every queue record as ``(replica, req_id, kind, arrival_ms,
+        dispatch_ms)``."""
+        return [k + v for k, v in self._queue.items()]
 
     # -- per-stream lifecycle ------------------------------------------
     def stream_begin(self, name: str, t: float, *, replica: int = 0,
@@ -420,7 +549,8 @@ class Tracer:
     def to_events(self) -> list[dict]:
         """Chrome trace-event list: pid 0 carries the per-stream async
         spans; pid ``1 + replica`` carries that replica's engine track
-        (tid 0) and one track per touched slot (tid ``1 + slot``)."""
+        (tid 0), its host regions (tid ``HOST_TID``) and one track per
+        touched slot (tid ``1 + slot``)."""
         ev = [{"ph": "M", "name": "process_name", "pid": 0, "tid": 0,
                "args": {"name": "streams"}}]
         replicas, slots = set(), set()
@@ -455,6 +585,17 @@ class Tracer:
             ev.append({"ph": "i", "s": "t", "name": kind, "cat": "engine",
                        "ts": self._us(t), "pid": 1 + rep, "tid": tid,
                        "args": args})
+        hosts = set()
+        for name, t0, t1, _, rep, args in self.regions():
+            if t1 is None:
+                continue
+            hosts.add(rep)
+            ev.append({"ph": "X", "name": name, "cat": "host",
+                       "ts": self._us(t0),
+                       "dur": max(self._us(t1 - t0), 0.0),
+                       "pid": 1 + rep, "tid": HOST_TID,
+                       "args": dict(args)})
+        replicas |= hosts
         for rec in self._streams.values():
             sid = str(rec.uid)
             name = f"{rec.name}-{rec.uid}"
@@ -481,6 +622,9 @@ class Tracer:
                        "tid": 0, "args": {"name": f"replica-{rep}"}})
             ev.append({"ph": "M", "name": "thread_name", "pid": 1 + rep,
                        "tid": 0, "args": {"name": "engine"}})
+        for rep in sorted(hosts):
+            ev.append({"ph": "M", "name": "thread_name", "pid": 1 + rep,
+                       "tid": HOST_TID, "args": {"name": "host"}})
         for rep, slot in sorted(slots):
             ev.append({"ph": "M", "name": "thread_name", "pid": 1 + rep,
                        "tid": 1 + slot, "args": {"name": f"slot-{slot}"}})
@@ -490,6 +634,8 @@ class Tracer:
         return {"traceEvents": self.to_events(), "displayTimeUnit": "ms",
                 "synera": {"spans": len(self._spans),
                            "instants": len(self._instants),
+                           "regions": len(self._regions),
+                           "queued": len(self._queue),
                            "streams": len(self._streams),
                            "dropped": self.dropped}}
 
@@ -498,3 +644,4 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.to_dict(), f)
         return path
+

@@ -14,6 +14,7 @@ imported: only one process may load the TPU compiler's library, and
 under several test workers the one given this file is that process.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -55,11 +56,14 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, sharding, name, *shapes):
+    """Compile ``fn`` and require the kernel in the program under its
+    stable ``name`` (the instruction a device trace's op carries)."""
     args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
             for s, dt in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    assert re.search(rf"%{name}(\.\d+)? = .*custom-call\(", text), name
 
 
 BF, I32 = jnp.bfloat16, jnp.int32
@@ -70,20 +74,22 @@ POOL = [((NB, BS, NH, HD), BF), ((NB, BS, NH, HD), BF)]
 def test_decode_gqa_dense(one_chip):
     _compile(lambda q, k, v, qp, kp: decode_attention(
         q, k, v, qp, kp, block_kv=1024, interpret=False), one_chip,
-        ((B, NH, HD), BF), *DENSE_KV, ((B,), I32), ((B, S), I32))
+        "decode_gqa", ((B, NH, HD), BF), *DENSE_KV, ((B,), I32),
+        ((B, S), I32))
 
 
 def test_partial_prefill_dense(one_chip):
     _compile(lambda q, k, v, qp, kp: partial_prefill_attention(
         q, k, v, qp, kp, block_kv=1024, interpret=False), one_chip,
-        ((B, C, NH, HD), BF), *DENSE_KV, ((B, C), I32), ((B, S), I32))
+        "partial_prefill", ((B, C, NH, HD), BF), *DENSE_KV, ((B, C), I32),
+        ((B, S), I32))
 
 
 @pytest.mark.parametrize("block_kv,splits", PAGED)
 def test_decode_gqa_paged(one_chip, block_kv, splits):
     _compile(lambda q, k, v, qp, pp, bt: decode_attention_paged(
         q, k, v, qp, pp, bt, block_kv=block_kv, kv_splits=splits,
-        interpret=False), one_chip,
+        interpret=False), one_chip, "decode_gqa_paged",
         ((B, NH, HD), BF), *POOL, ((B,), I32), ((NB, BS), I32),
         ((B, MBPS), I32))
 
@@ -92,7 +98,7 @@ def test_decode_gqa_paged(one_chip, block_kv, splits):
 def test_partial_prefill_paged(one_chip, block_kv, splits):
     _compile(lambda q, k, v, qp, pp, bt: partial_prefill_attention_paged(
         q, k, v, qp, pp, bt, block_kv=block_kv, kv_splits=splits,
-        interpret=False), one_chip,
+        interpret=False), one_chip, "partial_prefill_paged",
         ((B, C, NH, HD), BF), *POOL, ((B, C), I32), ((NB, BS), I32),
         ((B, MBPS), I32))
 
@@ -103,5 +109,5 @@ def test_attn_importance(one_chip, tq):
     256-row block."""
     kv = ((1, S, SLM_NH, SLM_HD), BF)
     _compile(lambda q, k, v, qp, kp: attn_with_importance(
-        q, k, v, qp, kp, interpret=False), one_chip,
+        q, k, v, qp, kp, interpret=False), one_chip, "attn_importance",
         ((1, tq, SLM_NH, SLM_HD), BF), kv, kv, ((1, tq), I32), ((1, S), I32))

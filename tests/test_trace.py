@@ -13,6 +13,11 @@ Two families of invariants:
   shared-prefix + swap paged fleet), and the exported Chrome
   trace-event JSON passes the structural checker shipped in
   ``tools/check_trace.py``.
+
+* **Host regions and queue records** — regions nest as opened, every
+  engine region lies inside an event-loop step, they leave the charge
+  spans (and so the attribution) alone, and every fed request has one
+  queue record stamped with the iteration that first fed it.
 """
 import http.client
 import json
@@ -32,11 +37,14 @@ from repro.serving import synergy as SY
 from repro.serving.device import DeviceRuntime
 from repro.serving.engine import CloudEngine
 from repro.serving.gateway import protocol as P
-from repro.serving.link import SimClock, Timeline
+from repro.serving.link import RealClock, SimClock
 from repro.serving.router import ReplicaRouter
-from repro.serving.server import WAIT_CLOUD, build_fleet
-from repro.serving.trace import (NULL_TRACER, StreamTimeline, Tracer,
-                                 hist_add, hist_from, hist_merge, hist_new)
+from repro.serving.scheduler import VerificationAwareScheduler
+from repro.serving.server import (WAIT_CLOUD, SyneraServer,
+                                  aggregate_server_stats, build_fleet)
+from repro.serving.trace import (HOST_TID, NULL_TRACER, StreamTimeline,
+                                 Tracer, hist_add, hist_from, hist_merge,
+                                 hist_new)
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "tools"))
 import check_trace  # noqa: E402  (tools/check_trace.py)
@@ -48,11 +56,6 @@ import check_trace  # noqa: E402  (tools/check_trace.py)
 
 def _assert_sums(tl: StreamTimeline):
     assert abs(tl.bucket_sum - tl.t_ms) <= 1e-9 * max(1.0, tl.t_ms)
-
-
-def test_timeline_is_link_alias():
-    # serving/link.py's Timeline moved into serving/trace.py
-    assert Timeline is StreamTimeline
 
 
 def test_advance_kinds_map_to_buckets():
@@ -188,6 +191,79 @@ def test_null_tracer_is_inert():
     NULL_TRACER.instant("x")
     with pytest.raises(RuntimeError):
         NULL_TRACER.export("/tmp/never.json")
+
+
+def test_null_tracer_region_is_one_shared_no_op():
+    region = NULL_TRACER.region("synera.step")
+    assert NULL_TRACER.region("synera.engine.feed", 3) is region
+    with region as r:
+        assert r is region
+    assert NULL_TRACER.queued(1) is None
+    NULL_TRACER.queue(1, "verify", 0.0, 1.0)
+    assert not hasattr(NULL_TRACER, "_regions")
+    assert NULL_TRACER.to_events() == []
+
+
+def test_regions_nest_as_opened_and_export_on_the_host_track():
+    clock = SimClock()
+    tr = Tracer(clock)
+    with tr.region("synera.step", 2) as step:
+        clock.advance(1.0)
+        with tr.region("synera.sched.plan"):
+            clock.advance(2.0)
+        with tr.region("synera.engine.feed"):
+            with tr.region("synera.engine.dispatch"):
+                clock.advance(3.0)
+        step.args.update(kind="verify", rids=(4, 5))
+    with tr.region("synera.step"):
+        pass
+    regs = tr.regions()
+    assert [(n, t0, t1, parent, rep) for n, t0, t1, parent, rep, _ in regs] \
+        == [("synera.step", 0.0, 6.0, -1, 2),
+            ("synera.sched.plan", 1.0, 3.0, 0, 2),
+            ("synera.engine.feed", 3.0, 6.0, 0, 2),
+            ("synera.engine.dispatch", 3.0, 6.0, 2, 2),
+            ("synera.step", 6.0, 6.0, -1, 0)]
+    assert regs[0][5] == {"kind": "verify", "rids": (4, 5)}
+    host = [e for e in tr.to_events() if e.get("tid") == HOST_TID]
+    assert {(e["ph"], e["pid"]) for e in host} == {("X", 3), ("X", 1),
+                                                   ("M", 3), ("M", 1)}
+    names = [e["args"]["name"] for e in host if e["ph"] == "M"]
+    assert names == ["host", "host"]
+    step_ev = [e for e in host if e["name"] == "synera.step"][0]
+    assert step_ev["dur"] == 6000.0 and step_ev["args"]["rids"] == (4, 5)
+    errors, _ = check_trace.check_events(tr.to_events())
+    assert errors == [], errors
+
+
+def test_regions_and_queue_records_leave_window_parts_unchanged():
+    def charged(with_regions):
+        clock = SimClock()
+        tr = Tracer(clock)
+        for t0, t1, kind, rids in [(0.0, 10.0, "prefill", (1,)),
+                                   (10.0, 20.0, "verify", (3,)),
+                                   (20.0, 30.0, "verify", (2,))]:
+            if with_regions:
+                clock.advance_to(t0)
+                with tr.region("synera.step"):
+                    clock.advance_to(t1)
+                tr.queue(rids[0], kind, t0 - 1.0, t0)
+            tr.span(t0, t1, kind, rids=rids)
+        return tr.window_parts(0.0, 35.0, vrid=2, prefill_rid=1)
+
+    assert charged(True) == charged(False) == [
+        ("cloud", 10.0), ("wait", 10.0), ("cloud", 10.0), ("other", 5.0)]
+
+
+def test_queue_keeps_the_first_dispatch_per_replica():
+    tr = Tracer(SimClock())
+    tr.queue(7, "verify", 1.0, 4.0)
+    tr.queue(7, "verify", 1.0, 9.0)            # a refeed after a rewind
+    tr.queue(7, "verify", 2.0, 3.0, replica=1)
+    assert tr.queued(7) == ("verify", 1.0, 4.0)
+    assert tr.queued(7, 1) == ("verify", 2.0, 3.0)
+    assert sorted(tr.queue_records()) == [(0, 7, "verify", 1.0, 4.0),
+                                          (1, 7, "verify", 2.0, 3.0)]
 
 
 # ---------------------------------------------------------------------------
@@ -387,6 +463,182 @@ def test_gateway_traces_endpoint_and_metrics_histograms(dev, pair):
         gw2.close()
 
 
+def _wall_run(dev, pair, prompts, max_new, tracer=None):
+    clock = RealClock() if tracer is None else tracer.clock
+    server = SyneraServer(dev, _mk_engine(pair), clock=clock,
+                          clamp_arrivals=True, tracer=tracer)
+    return server, _tokens(server.serve(prompts, max_new, concurrency=2))
+
+
+def test_wall_clock_regions_nest_inside_steps(dev, pair):
+    """On the wall clock the tracer's regions nest as the program opened
+    them: every engine and scheduler region lies inside an event-loop
+    step, each step that fed requests names them, and the run's tokens
+    are those of the untraced run."""
+    prompts = _prompts(3, length=10, seed=21)
+    _, base = _wall_run(dev, pair, prompts, 6)
+    tracer = Tracer(RealClock())
+    server, toks = _wall_run(dev, pair, prompts, 6, tracer)
+    assert toks == base
+    regs = tracer.regions()
+    assert all(r[2] is not None for r in regs)   # every region closed
+    names = {r[0] for r in regs}
+    assert {"synera.step", "synera.server.open", "synera.sched.plan",
+            "synera.engine.feed", "synera.engine.prefill",
+            "synera.engine.blocks", "synera.engine.dispatch",
+            "synera.engine.sync", "synera.sched.accept",
+            "synera.server.deliver"} <= names
+    for name, t0, t1, parent, _, _ in regs:
+        assert t0 <= t1
+        if parent >= 0:
+            p = regs[parent]
+            assert p[1] <= t0 and t1 <= p[2]
+        if name.startswith(("synera.engine.", "synera.sched.")):
+            up = parent
+            while up >= 0 and regs[up][0] != "synera.step":
+                up = regs[up][3]
+            assert up >= 0, name
+    fed = [r for r in regs if r[0] == "synera.step" and r[5]]
+    kinds = {r[5]["kind"] for r in fed}
+    assert kinds == {"prefill", "verify"}
+    spans = [s for s in tracer._spans if s[2] in ("prefill", "verify")]
+    assert sorted(tuple(r[5]["rids"]) for r in fed) == \
+        sorted(tuple(s[4]) for s in spans)
+
+
+def test_every_fed_request_has_one_queue_record(dev, pair):
+    """Each request a charge span fed has exactly one queue record: its
+    arrival, and the start of the first iteration that fed it."""
+    tracer = Tracer(RealClock())
+    server, _ = _wall_run(dev, pair, _prompts(3, length=10, seed=22), 6,
+                          tracer)
+    first = {}
+    for t0, _, kind, _, rids, *_ in tracer._spans:
+        for rid in rids:
+            first.setdefault(rid, (kind, t0))
+    recs = tracer.queue_records()
+    assert len(recs) == len(first) > 0
+    for rep, rid, kind, arrival, dispatch in recs:
+        assert rep == 0 and (kind, dispatch) == first[rid]
+        assert dispatch >= arrival
+    kinds = [k for _, _, k, _, _ in recs]
+    assert kinds.count("prefill") == 3 and "verify" in kinds
+    queued = [c for rec in tracer._streams.values()
+              for c in rec.children if c[0] == "queued"]
+    assert len(queued) == len(recs)
+
+
+def test_engine_counters_and_specialize_instants(pair):
+    """The engine counts rows each sub-chunk dispatched (padded to its
+    bucket) and fed, block-table uploads and host syncs, and marks the
+    first use of each (step, bucket) on the trace."""
+    eng = _mk_engine(pair, feed_buckets=(8, 16))
+    tracer = Tracer(SimClock())
+    eng.set_tracer(tracer)
+    B = eng.max_slots
+    toks = np.ones((B, 20), np.int32)
+    pos = np.full((B, 20), -1, np.int32)
+    pos[0] = np.arange(20)                     # 16 + a 4-wide tail -> 8
+    eng.alloc_prompt(0, [1] * 20)
+    eng.prefill(toks, pos)
+    assert eng.rows_computed == {"verify": 0, "prefill": B * (16 + 8)}
+    assert eng.rows_fed == {"verify": 0, "prefill": 20}
+    assert eng.host_syncs == 1                 # the last row's sub-chunk
+    uploads = eng.table_uploads
+    assert uploads >= 1
+    assert eng.table_upload_bytes == uploads * eng.allocator.table.nbytes
+    tok = np.zeros((B, 3), np.int32)
+    vpos = np.full((B, 3), -1, np.int32)
+    vpos[0] = [20, 21, 22]
+    sel = np.full((B, eng.verify_rows_max), -1, np.int32)
+    sel[0, :3] = [0, 1, 2]
+    eng.feed(tok, vpos, sel_idx=sel, need_dists=False)
+    eng.feed(tok, vpos, sel_idx=sel)
+    eng.feed(tok, vpos, sel_idx=sel)
+    assert eng.rows_computed["verify"] == 3 * B * 8
+    assert eng.rows_fed["verify"] == 9 and eng.host_syncs == 4
+    # prefill took the greedy step at 16 and 8, the greedy feed nothing
+    # new, the first feed with dists the full step at 8
+    spec = [n for _, k, _, _, _, n in tracer._instants if k == "specialize"]
+    assert spec == [16, 8, 8]
+    names = [r[0] for r in tracer.regions()]
+    assert names.count("synera.engine.prefill") == 1
+    assert names.count("synera.engine.feed") == 3
+    assert names.count("synera.engine.sync") == 4
+
+
+def test_step_without_an_iteration_names_no_batch(dev, pair):
+    """A step that runs no scheduler iteration (every session done)
+    carries no kind or request ids, even right after a step that fed."""
+    tracer = Tracer(RealClock())
+    server, _ = _wall_run(dev, pair, _prompts(2, length=10, seed=23), 4,
+                          tracer)
+    fed = [r for r in tracer.regions() if r[0] == "synera.step" and r[5]]
+    assert fed
+    n = len(tracer.regions())
+    assert server.step() is False
+    last = tracer.regions()[n:]
+    assert [r[0] for r in last] == ["synera.step", "synera.server.open"]
+    assert last[0][5] == {}
+
+
+def test_server_installs_its_tracer_on_the_engine(dev, pair):
+    """The server, not the scheduler, gives its engine (and the
+    engine's block pool and swap tier) the tracer; a later server over
+    the same engine takes it over."""
+    eng = _mk_engine(pair, swap=True)
+    parts = (eng, eng.allocator, eng.swap_manager)
+    clock = SimClock()
+    tracer = Tracer(clock)
+    VerificationAwareScheduler(eng, clock=clock, tracer=tracer, replica=1)
+    assert all(p.tracer is NULL_TRACER for p in parts)
+    SyneraServer(dev, eng, clock=clock, tracer=tracer, replica=1)
+    assert all(p.tracer is tracer and p.trace_replica == 1 for p in parts)
+    SyneraServer(dev, eng, clock=clock)
+    assert all(p.tracer is NULL_TRACER and p.trace_replica == 0
+               for p in parts)
+
+
+def test_server_stats_expose_the_host_counters(dev, pair):
+    """The engine's and scheduler's boundary counters reach
+    ServerStats, and so the gateway's /metrics, summed over a fleet."""
+    prompts = _prompts(3, length=10, seed=24)
+    clock = SimClock()
+    fleet = build_fleet(dev, [_mk_engine(pair), _mk_engine(pair)],
+                        clock=clock)
+    ReplicaRouter(fleet, policy="round-robin").serve(prompts, 4)
+    per = [srv.server_stats() for srv in fleet]
+    for srv, st in zip(fleet, per):
+        eng = srv.engine
+        assert st.rows_computed == sum(eng.rows_computed.values()) > 0
+        assert st.rows_fed == sum(eng.rows_fed.values()) > 0
+        assert st.rows_fed < st.rows_computed
+        assert (st.table_uploads, st.table_upload_bytes, st.host_syncs) \
+            == (eng.table_uploads, eng.table_upload_bytes, eng.host_syncs)
+        assert st.table_uploads > 0 and st.host_syncs > 0
+        assert st.prompt_tokens_done == srv.sched.prompt_tokens_done
+    total = aggregate_server_stats(per)
+    for k in ("rows_computed", "rows_fed", "table_uploads",
+              "table_upload_bytes", "host_syncs", "prompt_tokens_done"):
+        assert getattr(total, k) == sum(getattr(st, k) for st in per)
+    assert total.prompt_tokens_done == sum(len(p) for p in prompts)
+    text = P.metrics_text(total.as_dict())
+    assert f"synera_table_uploads {total.table_uploads}\n" in text
+    assert f"synera_host_syncs {total.host_syncs}\n" in text
+
+
+def test_scheduler_counts_completed_prompt_tokens(dev, pair):
+    prompts = _prompts(3, shared=3)
+    server = SyneraServer(dev, _mk_engine(pair), clock=SimClock())
+    server.serve(prompts, 4, concurrency=3)
+    st = server.sched
+    assert st.prompt_tokens_done == sum(len(p) for p in prompts)
+    # a stream admitted while another holds the shared 16-token lead
+    # adopts its block instead of feeding it
+    served = st.prompt_tokens_done - st.prefill_fed_tokens
+    assert served > 0 and served % 16 == 0
+
+
 @settings(max_examples=5, deadline=None)
 @given(st.integers(min_value=1, max_value=4),
        st.integers(min_value=2, max_value=10),
@@ -405,3 +657,8 @@ def test_tracing_byte_identity_property(dev, pair, n, max_new, conc):
     assert traced.outputs == base.outputs
     for m in traced.metrics:
         _assert_sums(m.timeline)
+    # the traced run also recorded host regions and queue records
+    tracer = traced.extras["tracer"]
+    assert any(r[0] == "synera.engine.feed" for r in tracer.regions())
+    assert {k for _, _, k, _, _ in tracer.queue_records()} == {
+        "prefill", "verify"}
