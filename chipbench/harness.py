@@ -27,7 +27,7 @@ from chipbench import cells, flops, replay
 
 HERE = Path(__file__).resolve().parent
 TRACE_DIR = HERE.parent / ".chipbench_trace"
-DRAIN_S = 60.0           # how long past the window a due call may take
+DRAIN_S = 60.0           # how long past the window the drain may run
 TRACE_S = 4.0            # traced part of the window (--trace 1)
 
 
@@ -200,6 +200,7 @@ class Run:
         self.counting = Counting(self.cfg)
         self.records: list[replay.StreamRecord] = []
         self._awaiting: list[replay.StreamRecord] = []   # prefill pending
+        self.checked: list[int] | None = None   # stream ids compared
 
     # -- set-up ---------------------------------------------------------
     def build(self):
@@ -337,10 +338,18 @@ class Run:
                     out.append(c)
         return out
 
+    def pool(self) -> list:
+        """The streams the check may take: those whose scheduled arrival
+        falls in the window.  ``replay.schedule`` gives every seed the
+        same set of window streams, so the pool is a property of the
+        schedule, not of how fast the verifier answers."""
+        return [r for r in self.records if self.in_window(r.start_ms)]
+
     def serve(self):
         """Open streams on their schedule, step the front end, and stop
-        once every call due in the window has its reply (or the drain
-        limit passed)."""
+        once every call due in the window has its reply and every pool
+        stream is done (or the drain limit passed); arrivals of the
+        drain keep coming meanwhile."""
         import jax
 
         mix = self.mix
@@ -401,8 +410,12 @@ class Run:
                     str(TRACE_DIR / "**" / "*.xplane.pb"), recursive=True))[-1]
             if state == "drain":
                 pending = self._window_open()
-                if not pending or now >= self.w1 + DRAIN_S * 1e3:
+                unfinished = [r for r in self.pool() if not r.done]
+                if not (pending or unfinished) \
+                        or now >= self.w1 + DRAIN_S * 1e3:
                     self.unanswered = len(pending)
+                    self.unfinished = len(unfinished)
+                    self.drain_s = (now - self.w1) / 1e3
                     break
             n0 = self.server.sched.iterations
             self.server.step()
@@ -505,12 +518,13 @@ class Run:
 
     # -- correctness --------------------------------------------------------
     def sample_streams(self) -> list:
-        """Finished streams with a call due in the window, the longest
+        """Finished pool streams that the cloud served, the longest
         first, then in an order drawn from the seed, until they hold the
         mix's ``check_tokens`` cloud-served tokens: ``(tokens, served)``
-        with ``served`` the ``(position, token)`` pairs the cloud gave."""
-        done = [r for r in self.records if r.done and r.served
-                and any(self.in_window(c.due_ms) for c in r.calls)]
+        with ``served`` the ``(position, token)`` pairs the cloud gave.
+        The ids of the streams taken are kept in ``checked``."""
+        done = [r for r in self.pool() if r.done and r.served]
+        self.checked = []
         if not done:
             return []
         rng = np.random.default_rng([self.seed % (1 << 63), 7])
@@ -522,6 +536,7 @@ class Run:
                 break
             pick.append(j)
             served += len(done[j].served)
+        self.checked = [done[j].spec.sid for j in sorted(pick)]
         return [(np.asarray(done[j].seq, np.int32), done[j].served)
                 for j in sorted(pick)]
 
@@ -536,9 +551,11 @@ class Run:
         """Compare every token the cloud served the sampled streams (or
         ``streams``) with the plain reference: the widest gap by which a
         served token's logit lies below the reference's best, over at
-        least the mix's ``check_tokens`` served tokens."""
+        least the mix's ``check_tokens`` served tokens; and every pool
+        stream finished."""
         if streams is None:
             streams = self.sample_streams()
+        pool = self.pool()
         self.free_program()
         ref = _load_reference(self.model["reference"])
         t = time.perf_counter()
@@ -550,6 +567,7 @@ class Run:
             "max_logit_gap": {"value": max(gaps) if gaps else None,
                               "limit": self.model["check"]["max_logit_gap"]},
             "unanswered_calls": {"value": self.unanswered, "limit": 0},
+            "unfinished_streams": {"value": self.unfinished, "limit": 0},
             "served_tokens": {"value": len(gaps),
                               "min": self.mix["check_tokens"]},
             "tokens_out_of_vocab": {"value": int(not in_vocab), "limit": 0},
@@ -559,7 +577,10 @@ class Run:
                                         if "limit" in c else
                                         c["value"] >= c["min"])
             for c in checks.values())
-        log("check", streams=len(streams), reference_s=time.perf_counter() - t,
+        log("check", streams=len(streams), checked=self.checked,
+            pool_streams=len(pool),
+            pool_served_tokens=sum(len(r.served) for r in pool),
+            reference_s=time.perf_counter() - t,
             gap_p50=percentile(gaps, 50) if gaps else None,
             correct=self.correct,
             **{k: v["value"] for k, v in checks.items()})
@@ -608,6 +629,7 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool, devices,
     life = run.lifetimes_s() or [float("nan")]
     log("serve", attempted=run.attempted, failed=run.failed,
         samples=run.samples, streams_opened=len(run.records),
+        drain_s=run.drain_s,
         lateness_p50_ms=percentile(run.lateness_ms, 50),
         lateness_max_ms=max(run.lateness_ms),
         compiles_in_window=run.compiles_in_window,

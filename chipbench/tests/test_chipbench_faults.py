@@ -77,7 +77,7 @@ def test_the_control_in_the_programs_place_is_not_correct(seed):
              num_hidden_layers=4, vocab_size=32768)
     run = harness.Run(cell, seed, 1.0, False, jax.devices()[:1], 0.0)
     run.params = weights.make_params(run.cfg, seed, run.devices[0])
-    run.engine, run.unanswered = None, 0
+    run.engine, run.unanswered, run.unfinished = None, 0, 0
     rng = np.random.default_rng(seed)
     seqs = [rng.integers(1, 32768, 400).astype(np.int32) for _ in range(6)]
     ref = harness._load_reference(m["reference"])
