@@ -2,13 +2,15 @@
 
 The paged ``decode_gqa``/``partial_prefill`` variants stream a slot's
 KV out of the shared block pool through its block table (vLLM /
-PagedAttention layout).  Both kernels share the same three-layer
-streaming design, and this module owns the pieces common to both:
+PagedAttention layout).  ``decode_gqa`` walks the table as grid steps;
+``partial_prefill`` loops over only its slot's live steps inside a
+program and issues their DMAs itself.  This module owns the pieces
+common to both:
 
-* **Fused-DMA layout** (:func:`fused_layout`): each grid step DMAs
+* **Fused-DMA layout** (:func:`fused_layout`): each KV step DMAs
   ``fuse = block_kv // block_size`` consecutive block-table entries —
   ``fuse`` independent ``(bs, hd)`` pool-block descriptors issued
-  together in one pipeline step — so the sequential KV axis shrinks
+  together — so the sequential KV axis shrinks
   from ``max_bps`` single-block steps to ``ceil(max_bps / fuse)``
   dense-sized transfers and the per-step DMA latency is amortized by
   the fusion factor.
@@ -22,10 +24,13 @@ streaming design, and this module owns the pieces common to both:
 * **Split-KV combine** (:func:`combine_splits`): the flash-decode
   epilogue.  With ``kv_splits > 1`` the sequence axis is cut into
   ``splits`` contiguous runs of table entries, each owned by a
-  *parallel* grid program that writes partial online-softmax state
+  *parallel* program that writes partial online-softmax state
   ``(m, l, acc)``; the epilogue merges the partials.  At
   ``kv_splits=1`` the merge degenerates to the single-pass
   normalization bit-for-bit (``w = exp(m - m) = 1`` exactly).
+
+* **Live steps** (:func:`live_steps`): the fused steps of a slot's table
+  its query rows can attend to, so a kernel walks those and no more.
 
 * **Grid accounting** (:func:`paged_grid_info`): the bench reads the
   fused grid shape from here so the step-count reduction is asserted,
@@ -60,13 +65,21 @@ def subblock_mapped(bt_ref, slot, entry, max_bps: int):
 
 def fused_positions(bt_ref, kp_refs, slot, base, max_bps: int):
     """Positions of one fused grid step's ``fuse`` sub-blocks as one
-    (1, fuse * bs) int32 row; a sub-block whose table entry is unmapped
-    or past the table reads as -1 (invalid), so the caller's ``pos >= 0``
-    test masks it whole.  The rows are joined along lanes in int32: the
-    TPU compiler accepts that join, and not one of boolean masks."""
+    (1, fuse * bs) int32 row, from their (1, 1, bs) blocks."""
+    return join_positions(bt_ref, [r[0] for r in kp_refs], slot, base,
+                          max_bps)
+
+
+def join_positions(bt_ref, rows, slot, base, max_bps: int):
+    """Join the (1, bs) position rows of the table entries ``base``,
+    ``base + 1``, ... into one (1, len(rows) * bs) int32 row; a sub-block
+    whose table entry is unmapped or past the table reads as -1
+    (invalid), so the caller's ``pos >= 0`` test masks it whole.  The
+    rows are joined along lanes in int32: the TPU compiler accepts that
+    join, and not one of boolean masks."""
     return jnp.concatenate(
         [jnp.where(subblock_mapped(bt_ref, slot, base + j, max_bps),
-                   r[0], -1) for j, r in enumerate(kp_refs)], axis=1)
+                   r, -1) for j, r in enumerate(rows)], axis=1)
 
 
 def fused_layout(max_bps: int, block_size: int, block_kv: int | None,
@@ -92,6 +105,18 @@ def fused_layout(max_bps: int, block_size: int, block_kv: int | None,
     splits = max(1, min(kv_splits, n_fused))
     spb = -(-n_fused // splits)
     return fuse, splits, spb
+
+
+def live_steps(q_pos, max_bps: int, block_size: int, fuse: int):
+    """Fused steps of each slot's table that its query rows can attend
+    to, for ``q_pos`` (B, C) (-1 = padding): none for a slot whose rows
+    are all padding, the whole table once its stream has wrapped past
+    ``s_max = max_bps * block_size`` (position p lives in entry
+    ``(p % s_max) // block_size``), else the entries up to that of its
+    highest position.  Takes numpy or jax arrays alike, so the host
+    counts what the kernel walks."""
+    entries = (q_pos.max(axis=1) // block_size + 1).clip(0, max_bps)
+    return -(-entries // fuse)
 
 
 def paged_grid_info(max_bps: int, block_size: int, block_kv: int | None,

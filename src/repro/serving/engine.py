@@ -51,6 +51,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import paged as PG
 from repro.models import model as M
 from repro.models.steps import (make_cloud_decode_step, make_cloud_verify_step,
                                 make_decode_step, make_verify_step)
@@ -721,6 +722,11 @@ class CloudEngine:
         self.bytes_to_host = 0
         self.rows_computed = {"verify": 0, "prefill": 0}
         self.rows_fed = {"verify": 0, "prefill": 0}
+        # (slot, KV head) fused KV steps of one layer's paged partial
+        # prefill over every whole table, per sub-chunk, and those the
+        # kernel walks
+        self.attn_kv_steps_grid = 0
+        self.attn_kv_steps_live = 0
         self.table_uploads = 0
         self.table_upload_bytes = 0
         self.host_syncs = 0
@@ -1089,6 +1095,9 @@ class CloudEngine:
             self._specialize("fused" if with_dists else "fused_greedy", Cb)
             self.rows_computed[kind] += B * Cb
             self.rows_fed[kind] += int((positions >= 0).sum())
+            if self.allocator is not None and Cb > 1 \
+                    and self.cfg.attn_impl == "pallas":
+                self._count_kv_steps(positions)
             step = self._step if with_dists else self._step_greedy
             out, self.cache = step(
                 self.params, self.cache,
@@ -1098,6 +1107,21 @@ class CloudEngine:
                 self._put(np.asarray(sel_idx, np.int32)),
                 self._put(np.asarray(last_local, np.int32)))
         return out
+
+    def _count_kv_steps(self, positions):
+        """Count, from the host's block-table mirror (what
+        ``_sync_tables`` uploads) and the positions, the (slot, KV head)
+        fused steps of every slot's whole table and those the paged
+        partial-prefill kernel walks (``paged.live_steps``)."""
+        table = self.allocator.table
+        max_bps = table.shape[1]
+        fuse, splits, spb = PG.fused_layout(
+            max_bps, self.block_size, self.cfg.paged_block_kv,
+            self.cfg.paged_kv_splits)
+        nkv = self.cfg.n_kv_heads
+        self.attn_kv_steps_grid += table.shape[0] * nkv * splits * spb
+        self.attn_kv_steps_live += nkv * int(PG.live_steps(
+            positions, max_bps, self.block_size, fuse).sum())
 
     # ------------------------------------------------------------------
     def feed(self, tokens: np.ndarray, positions: np.ndarray,

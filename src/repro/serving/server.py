@@ -128,6 +128,8 @@ class ServerStats:
     # -- host boundaries (engine; docs/observability.md "On the chip") --
     rows_computed: int = 0             # rows the verify/prefill steps ran
     rows_fed: int = 0                  # of those, rows carrying a token
+    attn_kv_steps_grid: int = 0        # paged partial-prefill table steps
+    attn_kv_steps_live: int = 0        # of those, steps the kernel walked
     table_uploads: int = 0             # block-table uploads to the device
     table_upload_bytes: int = 0        # their bytes
     host_syncs: int = 0                # host reads blocked on step results
@@ -692,6 +694,8 @@ class SyneraServer:
             prompt_tokens_done=sched.prompt_tokens_done,
             rows_computed=sum(eng.rows_computed.values()),
             rows_fed=sum(eng.rows_fed.values()),
+            attn_kv_steps_grid=eng.attn_kv_steps_grid,
+            attn_kv_steps_live=eng.attn_kv_steps_live,
             table_uploads=eng.table_uploads,
             table_upload_bytes=eng.table_upload_bytes,
             host_syncs=eng.host_syncs,

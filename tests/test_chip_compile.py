@@ -103,6 +103,24 @@ def test_partial_prefill_paged(one_chip, block_kv, splits):
         ((B, MBPS), I32))
 
 
+@pytest.mark.parametrize("block_kv,splits", PAGED)
+@pytest.mark.parametrize("c,nkv", [
+    (256, 8),      # the prefill width at 4 query heads per KV head: 1024 rows
+    (C, 1),        # MQA: all 32 heads of a verify chunk in one program
+    (256, 1),      # MQA at the prefill width: the heads split over programs
+])
+def test_partial_prefill_paged_folded_heads(one_chip, block_kv, splits, c,
+                                            nkv):
+    """The paged kernel stacks the query heads of a KV head into one
+    program's rows; the widest such block must still fit VMEM."""
+    pool = ((NB, BS, nkv, HD), BF)
+    _compile(lambda q, k, v, qp, pp, bt: partial_prefill_attention_paged(
+        q, k, v, qp, pp, bt, block_kv=block_kv, kv_splits=splits,
+        interpret=False), one_chip, "partial_prefill_paged",
+        ((B, c, NH, HD), BF), pool, pool, ((B, c), I32), ((NB, BS), I32),
+        ((B, MBPS), I32))
+
+
 @pytest.mark.parametrize("tq", [1, 256])
 def test_attn_importance(one_chip, tq):
     """The draft model's fused importance kernel: one decode row, and a

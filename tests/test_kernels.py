@@ -12,8 +12,10 @@ from repro.kernels.decode_gqa.decode_gqa import (decode_attention,
                                                  decode_attention_paged)
 from repro.kernels.decode_gqa.ref import decode_attention_ref
 from repro.kernels.partial_prefill.partial_prefill import (
-    partial_prefill_attention, partial_prefill_attention_paged)
+    heads_per_program, partial_prefill_attention,
+    partial_prefill_attention_paged)
 from repro.kernels.partial_prefill.ref import partial_prefill_ref
+from repro.kernels import paged as PG
 from repro.kernels.ssd_scan.ssd_scan import ssd_scan
 from repro.kernels.ssd_scan.ref import ssd_scan_ref, ssd_sequential_ref
 
@@ -111,20 +113,30 @@ def test_decode_gqa(B, S, nh, nkv, hd, window, dtype):
 # pool, dense oracle derived by gathering the pool through the tables.
 # ---------------------------------------------------------------------------
 
-def _random_paged_cache(rng, B, nb, bs, mbps, nkv, hd, lens, dtype):
+def _random_paged_cache(rng, B, nb, bs, mbps, nkv, hd, lens, dtype,
+                        shared=0):
     """Random pool + permuted tables backing ``lens[b]``-token slots,
-    plus the gathered dense-equivalent view."""
+    plus the gathered dense-equivalent view.  A slot longer than
+    ``s_max = mbps * bs`` has wrapped: entry ``j`` row ``r`` holds the
+    latest position ``p < L`` with ``p % s_max == j * bs + r``, as the
+    engine's circular window writes it.  ``shared`` leading entries of
+    slot 1 map slot 0's blocks, as prefix sharing does."""
     kp = jax.random.normal(jax.random.PRNGKey(7), (nb, bs, nkv, hd), dtype)
     vp = jax.random.normal(jax.random.PRNGKey(8), (nb, bs, nkv, hd), dtype)
     pos = np.full((nb, bs), -1, np.int32)
     bt = np.full((B, mbps), -1, np.int32)
     free = list(rng.permutation(nb))
+    s_max = mbps * bs
     for b, L in enumerate(lens):
-        for j in range(-(-L // bs)):
+        for j in range(min(mbps, -(-L // bs))):
+            if b == 1 and j < shared:
+                bt[b, j] = bt[0, j]
+                continue
             blk = free.pop()
             bt[b, j] = blk
-            valid = min(bs, L - j * bs)
-            pos[blk, :valid] = j * bs + np.arange(valid)
+            v = j * bs + np.arange(bs)
+            p = v + s_max * ((L - 1 - v) // s_max)
+            pos[blk] = np.where(p >= 0, p, -1)
     pos, bt = jnp.asarray(pos), jnp.asarray(bt)
     btc = jnp.where(bt < 0, nb, bt)
     s_max = mbps * bs
@@ -193,6 +205,62 @@ def test_partial_prefill_paged(B, C, nb, bs, mbps, nh, nkv, hd, window,
     np.testing.assert_allclose(np.asarray(o1, np.float32) * mask,
                                np.asarray(o2, np.float32) * mask,
                                **TOL[dtype])
+
+
+# Slots the paged partial prefill walks in part or not at all, each
+# against the dense oracle on every row, padding included (zeros):
+# (B, C, nb, bs, mbps, nh, nkv, hd, lens, rows per slot, shared entries)
+WALK_CASES = {
+    # slots 1 and 3 carry no valid row: they walk no table entry
+    "idle_slots": (4, 8, 40, 8, 8, 4, 2, 32, [40, 23, 9, 60], [5, 0, 3, 0],
+                   0),
+    # slot 0 wrapped past s_max = 64: its whole table is live
+    "wrapped": (2, 8, 24, 8, 8, 4, 2, 32, [150, 30], [8, 2], 0),
+    # slot 1's first two entries are slot 0's blocks (prefix sharing)
+    "shared_block": (2, 8, 24, 8, 6, 4, 2, 32, [44, 37], [8, 4], 2),
+    # 8 heads x 256 rows pass the row cap: two programs per KV head
+    "heads_split": (2, 256, 40, 16, 16, 8, 1, 16, [256, 300], [256, 20],
+                    0),
+    # live ranges of 1-2 entries, under one split's 4 at kv_splits 2
+    "short_split": (3, 4, 24, 8, 8, 4, 2, 32, [3, 12, 7], [2, 4, 1], 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+@pytest.mark.parametrize("blkv,splits", PAGED_VARIANTS + [(None, 2)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_partial_prefill_paged_walk(case, blkv, splits, dtype):
+    B, C, nb, bs, mbps, nh, nkv, hd, lens, rows, shared = WALK_CASES[case]
+    rng = np.random.default_rng(19)
+    kp, vp, pos, bt, kd, vd, posd = _random_paged_cache(
+        rng, B, nb, bs, mbps, nkv, hd, lens, dtype, shared=shared)
+    q = jax.random.normal(jax.random.PRNGKey(12), (B, C, nh, hd), dtype)
+    qp = np.full((B, C), -1, np.int32)
+    for b, nq in enumerate(rows):
+        qp[b, :nq] = lens[b] - nq + np.arange(nq)
+    qp = jnp.asarray(qp)
+    if case == "heads_split":
+        assert heads_per_program(nh // nkv, C, hd) < nh // nkv
+    o1 = partial_prefill_attention_paged(q, kp, vp, qp, pos, bt,
+                                         block_kv=blkv, kv_splits=splits)
+    o2 = partial_prefill_ref(q, kd, vd, qp, posd)
+    np.testing.assert_allclose(np.asarray(o1, np.float32),
+                               np.asarray(o2, np.float32), **TOL[dtype])
+    idle = [b for b, nq in enumerate(rows) if nq == 0]
+    assert not np.asarray(o1, np.float32)[idle].any()
+
+
+def test_live_steps_bound_the_walk():
+    """Live fused steps per slot: none for padding, up to the highest
+    position's entry, the whole table once wrapped; numpy (the host's
+    count) and jax (the kernel's) agree."""
+    qp = np.array([[-1, -1, -1], [0, 1, -1], [15, 16, 17], [63, 64, 65],
+                   [200, 201, 202]], np.int32)      # s_max = 4 * 16 = 64
+    host = PG.live_steps(qp, 4, 16, 1)
+    assert host.tolist() == [0, 1, 2, 4, 4]
+    assert PG.live_steps(qp, 4, 16, 3).tolist() == [0, 1, 1, 2, 2]
+    assert np.asarray(PG.live_steps(jnp.asarray(qp), 4, 16, 1)).tolist() \
+        == host.tolist()
 
 
 @pytest.mark.parametrize("kind", ["decode", "partial_prefill"])

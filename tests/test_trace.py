@@ -617,9 +617,12 @@ def test_server_stats_expose_the_host_counters(dev, pair):
             == (eng.table_uploads, eng.table_upload_bytes, eng.host_syncs)
         assert st.table_uploads > 0 and st.host_syncs > 0
         assert st.prompt_tokens_done == srv.sched.prompt_tokens_done
+        assert (st.attn_kv_steps_grid, st.attn_kv_steps_live) \
+            == (eng.attn_kv_steps_grid, eng.attn_kv_steps_live)
     total = aggregate_server_stats(per)
     for k in ("rows_computed", "rows_fed", "table_uploads",
-              "table_upload_bytes", "host_syncs", "prompt_tokens_done"):
+              "table_upload_bytes", "host_syncs", "prompt_tokens_done",
+              "attn_kv_steps_grid", "attn_kv_steps_live"):
         assert getattr(total, k) == sum(getattr(st, k) for st in per)
     assert total.prompt_tokens_done == sum(len(p) for p in prompts)
     text = P.metrics_text(total.as_dict())
