@@ -27,17 +27,18 @@ def cell(name: str) -> dict:
         raise SystemExit(f"chipbench: no workload {name!r} in BENCHMARK.json")
     w = found[0]
     conf = [c for c in bench["configs"] if c["name"] == w["config"]][0]
-    return assemble(name, ROOT / conf["file"], w["traffic"], w["chips"],
-                    bench)
+    return assemble(name, load_json(ROOT / conf["file"]),
+                    load_json(HERE / "traffic" / f"{w['traffic']}.json"),
+                    w["chips"], bench)
 
 
-def assemble(name: str, config_file, traffic: str, chips: int,
+def assemble(name: str, model: dict, mix: dict, chips: int,
              bench: dict) -> dict:
-    """A cell from its files; ``bench`` supplies the metrics, those with
-    a ``workloads`` key only where it names this cell."""
+    """A cell from its configuration and traffic mix; ``bench`` supplies
+    the metrics, those with a ``workloads`` key only where it names this
+    cell."""
     return dict(
-        name=name, chips=chips, model=load_json(config_file),
-        mix=load_json(HERE / "traffic" / f"{traffic}.json"),
+        name=name, chips=chips, model=model, mix=mix,
         end_to_end=[m for m in bench["end_to_end"]
                     if name in m.get("workloads", [name])],
         per_layer=[m for m in bench["per_layer"]

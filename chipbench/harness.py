@@ -4,10 +4,12 @@ metrics, and the comparison that decides ``correct``.
 The system under test is the program's own serving path: ``CloudEngine``
 (paged KV pool, fused ``cloud_verify`` step with the paged Pallas
 ``partial_prefill`` kernel), ``VerificationAwareScheduler`` and
-``SyneraServer`` on ``RealClock(pace=False)``, on one chip.  The
-benchmark brings the load (``replay.py``), the weights (``weights.py``)
-and the reference (``references/``); it wraps the calls into each layer
-to count rows and to put host spans around them.
+``SyneraServer`` on ``RealClock(pace=False)``: on one chip, or on N
+chips as N one-chip replicas (``server.build_fleet``) behind a
+``ReplicaRouter``.  The benchmark brings the load (``replay.py``), the
+weights (``weights.py``) and the reference (``references/``); it wraps
+the calls into each layer to count rows and to put host spans around
+them, named after the replica where there are several.
 """
 from __future__ import annotations
 
@@ -29,6 +31,7 @@ HERE = Path(__file__).resolve().parent
 TRACE_DIR = HERE.parent / ".chipbench_trace"
 DRAIN_S = 60.0           # how long past the window the drain may run
 TRACE_S = 4.0            # traced part of the window (--trace 1)
+PRIME_LIMIT_S = 600.0    # longest the priming prefills may take
 
 
 def log(tag: str, **fields):
@@ -143,7 +146,9 @@ class Counting:
                 kind, flops.step_flops(self.cfg, positions),
                 self.log.dispatched[n_dispatched:]))
 
-    def wrap(self, engine):
+    def wrap(self, engine, tag: str = ""):
+        """Count ``engine``'s calls; its host spans end in ``tag`` (the
+        replica's, in a fleet)."""
         for name in ("_step", "_step_greedy"):
             step = getattr(engine, name)
 
@@ -158,7 +163,7 @@ class Counting:
         def counted_feed(tokens, positions, targets=None, sel_idx=None,
                          need_dists=True):
             n = len(self.log.dispatched)
-            with self.span("bench.verify"):
+            with self.span("bench.verify" + tag):
                 out = feed(tokens, positions, targets, sel_idx,
                            need_dists=need_dists)
             self._account("verify", positions, n)
@@ -166,7 +171,7 @@ class Counting:
 
         def counted_prefill(tokens, positions):
             n = len(self.log.dispatched)
-            with self.span("bench.prefill"):
+            with self.span("bench.prefill" + tag):
                 out = prefill(tokens, positions)
             self._account("prefill", positions, n)
             return out
@@ -199,22 +204,34 @@ class Run:
         self.clog = CompileLog()
         self.counting = Counting(self.cfg)
         self.records: list[replay.StreamRecord] = []
-        self._awaiting: list[replay.StreamRecord] = []   # prefill pending
+        # per replica, the streams whose prompt prefill is pending
+        self._awaiting = [[] for _ in self.devices]
+        self.engines, self.servers = [], []     # one of each per chip
         self.checked: list[int] | None = None   # stream ids compared
+
+    def tag(self, replica: int) -> str:
+        """The suffix of a replica's host spans: none on one chip."""
+        return "" if len(self.devices) == 1 else f".r{replica}"
 
     # -- set-up ---------------------------------------------------------
     def build(self):
+        """One engine per chip, each with the seed's weights made on its
+        own chip; on one chip a bare ``SyneraServer``, on several one
+        server per engine on a shared clock behind a ``ReplicaRouter``
+        with the traffic's ``router`` policy."""
         import jax
 
         from chipbench import weights
         from repro.serving.engine import CloudEngine
         from repro.serving.link import RealClock
-        from repro.serving.server import SyneraServer
+        from repro.serving.router import ReplicaRouter
+        from repro.serving.server import build_fleet
 
         t = time.perf_counter()
-        self.params = weights.make_params(self.cfg, self.seed,
-                                          self.devices[0])
-        jax.block_until_ready(self.params)
+        params = [weights.make_params(self.cfg, self.seed, d)
+                  for d in self.devices]
+        jax.block_until_ready(params)
+        self.params = params[0]
         t_weights = time.perf_counter() - t
         kw = dict(max_slots=self.sv["slots"], s_max=self.sv["s_max"],
                   cache_impl="paged", block_size=self.sv["block_size"],
@@ -222,25 +239,33 @@ class Run:
                   retain_prefix=True)
         if "feed_buckets" in self.sv:
             kw["feed_buckets"] = tuple(self.sv["feed_buckets"])
-        if len(self.devices) != 1:
-            raise SystemExit("chipbench: a cell serves from one chip")
-        self.engine = CloudEngine(self.cfg, self.params,
-                                  device=self.devices[0], **kw)
-        self.counting.wrap(self.engine)
+        self.engines = [CloudEngine(self.cfg, p, device=d, **kw)
+                        for p, d in zip(params, self.devices)]
+        del params
+        for i, e in enumerate(self.engines):
+            self.counting.wrap(e, self.tag(i))
         self.clock = RealClock(pace=False)
         self.device = replay.ReplayDevice(self.mix, self.cfg.vocab,
                                           lambda: self.clock.now_ms)
-        self.server = SyneraServer(self.device, self.engine,
+        self.servers = build_fleet(self.device, self.engines,
                                    chunk=self.sv["chunk"], clock=self.clock)
+        if len(self.servers) == 1:
+            self.server = self.servers[0]
+        else:
+            route = self.mix["router"]
+            self.server = ReplicaRouter(
+                self.servers, policy=route["policy"],
+                replica_queue_cap=route["replica_queue_cap"])
         self._watch_prefills()
         t = time.perf_counter()
-        self.warm_shapes()
+        for e in self.engines:
+            self.warm_shapes(e)
         log("setup_parts", weights_s=t_weights,
             warm_shapes_s=time.perf_counter() - t,
             params=sum(x.size for x in jax.tree.leaves(self.params)),
             compile=self.clog.summary())
 
-    def warm_shapes(self):
+    def warm_shapes(self, e):
         """Compile, through the engine's own ``feed``, ``prefill``,
         ``alloc_prompt`` and ``reset_slot``, every program the traffic
         drives: the greedy step at each feed bucket (padding rows only:
@@ -253,7 +278,6 @@ class Run:
         which unshared prompts meet whenever a first token repeats)."""
         import jax
 
-        e = self.engine
         B = e.max_slots
         for b in e.feed_buckets:
             e.feed(np.zeros((B, b), np.int32),
@@ -277,17 +301,21 @@ class Run:
         jax.block_until_ready(e.cache)
 
     def _watch_prefills(self):
-        """Time each prompt prefill's completion on the host: wrap the
-        scheduler's iteration and match its ``prefill_done`` events to
-        the streams waiting on them."""
-        sched = self.server.sched
+        """Time each prompt prefill's completion on the host: wrap each
+        replica's scheduler iteration (its host span names the replica)
+        and match its ``prefill_done`` events to the streams waiting on
+        that replica."""
+        for i, server in enumerate(self.servers):
+            self._watch(server.sched, self._awaiting[i], "bench.step"
+                        + self.tag(i))
+
+    def _watch(self, sched, waiting: list, span: str):
         it = sched.run_iteration
-        waiting = self._awaiting
 
         def run_iteration():
             log = self.counting.log
             log.iteration_prefill_fed = 0
-            with self.counting.span("bench.step"):
+            with self.counting.span(span):
                 evs = it()
             done = [ev for ev in evs if ev.kind == "prefill_done"]
             if done:
@@ -315,15 +343,52 @@ class Run:
             s = self.server.open_session(spec.prompt, spec.max_new,
                                          arrival_ms=start_ms)
         rec.session = s
-        self._awaiting.append(rec)
+        # a router's placement; -1 where it degraded the stream to the
+        # device, which then finished at open without a cloud call
+        rec.replica = getattr(self.server, "owner", {}).get(id(s), 0)
+        if rec.replica >= 0:
+            self._awaiting[rec.replica].append(rec)
         self.records.append(rec)
         return rec
 
     def _next_due(self, now: float, next_arrival: float) -> float:
         due = [next_arrival]
-        for q in (self.server.sched.prefill_q, self.server.sched.verify_q):
-            due += [r.arrival_ms for r in q if r.arrival_ms > now]
+        for sched in (srv.sched for srv in self.servers):
+            for q in (sched.prefill_q, sched.verify_q):
+                due += [r.arrival_ms for r in q if r.arrival_ms > now]
         return min(due)
+
+    def _iterations(self) -> int:
+        return sum(srv.sched.iterations for srv in self.servers)
+
+    def _step(self, next_arrival: float):
+        """One step of the front end (every replica with work, in a
+        fleet); where no iteration ran, sleep until the next due time or
+        arrival, at most 2 ms."""
+        n0 = self._iterations()
+        self.server.step()
+        if self._iterations() == n0:
+            wait = min(self._next_due(self.clock.now_ms, next_arrival)
+                       - self.clock.now_ms, 2.0)
+            if wait > 0.05:
+                with self.counting.span("bench.wait"):
+                    time.sleep(wait / 1e3)
+
+    def prime(self, specs: list):
+        """Open the mix's priming streams together and serve until each
+        one's prompt prefill has completed (set-up: the caches then hold
+        every shared document)."""
+        for spec in specs:
+            self.open_stream(spec, self.clock.now_ms)
+        t = time.perf_counter()
+        # a stream that finished on the device waits for no prefill
+        while any(not r.done for w in self._awaiting for r in w):
+            if time.perf_counter() - t > PRIME_LIMIT_S:
+                raise RuntimeError("chipbench: the priming prefills took "
+                                   f"over {PRIME_LIMIT_S} s")
+            self._step(self.clock.now_ms + 5.0)
+        log("prime", streams=len(specs), seconds=time.perf_counter() - t,
+            prompt_tokens=sum(len(s.prompt) for s in specs))
 
     def _window_open(self) -> list:
         """Calls due in the window that have no reply yet."""
@@ -358,6 +423,10 @@ class Run:
             mix, self.seed, [warm_s, warm_s + self.seconds,
                              warm_s + self.seconds + DRAIN_S],
             self.cfg.vocab, self.sv["s_max"])
+        primed = replay.prime(mix, self.seed, self.cfg.vocab,
+                              self.sv["s_max"], len(specs))
+        if primed:
+            self.prime(primed)
         t_start = self.clock.now_ms
         arrivals = [t_start + s.arrival_s * 1e3 for s in specs]
         self.w0 = t_start + warm_s * 1e3
@@ -417,30 +486,32 @@ class Run:
                     self.unfinished = len(unfinished)
                     self.drain_s = (now - self.w1) / 1e3
                     break
-            n0 = self.server.sched.iterations
-            self.server.step()
-            if self.server.sched.iterations == n0:
-                nxt = arrivals[i] if i < len(arrivals) else now + 5.0
-                wait = min(self._next_due(self.clock.now_ms, nxt)
-                           - self.clock.now_ms, 2.0)
-                if wait > 0.05:
-                    with self.counting.span("bench.wait"):
-                        time.sleep(wait / 1e3)
+            self._step(arrivals[i] if i < len(arrivals) else now + 5.0)
         self.lateness_ms = lateness
         for s in list(self.server.sessions):   # free the live streams' slots
             if not s.done:
                 self.server.cancel(s)
 
     def _counters(self) -> dict:
-        st = self.server.sched
-        return dict(prefill_fed=self.counting.log.prefill_fed,
-                    opened=len(self.records), preemptions=st.preemptions,
-                    iterations=st.iterations,
-                    verify_iterations=st.verify_iterations,
-                    prefill_iterations=st.prefill_iterations,
-                    bytes_to_host=self.engine.bytes_to_host,
-                    queued_prefills=sum(1 for r in st.prefill_q
-                                        if r.arrival_ms <= self.clock.now_ms))
+        """Counts over every replica, and the router's own."""
+        scheds = [srv.sched for srv in self.servers]
+        now = self.clock.now_ms
+        out = dict(prefill_fed=self.counting.log.prefill_fed,
+                   opened=len(self.records),
+                   preemptions=sum(st.preemptions for st in scheds),
+                   iterations=sum(st.iterations for st in scheds),
+                   verify_iterations=sum(st.verify_iterations
+                                         for st in scheds),
+                   prefill_iterations=sum(st.prefill_iterations
+                                          for st in scheds),
+                   bytes_to_host=sum(e.bytes_to_host for e in self.engines),
+                   queued_prefills=sum(1 for st in scheds
+                                       for r in st.prefill_q
+                                       if r.arrival_ms <= now))
+        if len(self.servers) > 1:
+            out.update(affinity_hits=self.server.affinity_hits,
+                       degraded_streams=self.server.degraded_streams)
+        return out
 
     # -- end-to-end metrics ---------------------------------------------------
     def in_window(self, t_ms: float) -> bool:
@@ -453,6 +524,9 @@ class Run:
         lat = {"verify": [], "prefill": []}
         attempted = failed = tokens = 0
         for rec in self.records:
+            if rec.replica < 0 and self.in_window(rec.start_ms):
+                attempted += 1          # degraded to the device: failed
+                failed += 1
             for c in rec.calls:
                 if not self.in_window(c.due_ms) or _withdrawn(rec, c):
                     continue
@@ -487,8 +561,8 @@ class Run:
         peak = peaks_for(self.devices[0].device_kind)
         tr = None
         if getattr(self, "trace_path", None):
-            used = {d.id for d in self.devices}
-            tr = tracing.reduce(self.trace_path, used)
+            tr = tracing.reduce(self.trace_path,
+                                [d.id for d in self.devices])
         if tr is not None:
             from chipbench.tracing import is_kernel
 
@@ -518,21 +592,26 @@ class Run:
 
     # -- correctness --------------------------------------------------------
     def sample_streams(self) -> list:
-        """Finished pool streams that the cloud served, the longest
-        first, then in an order drawn from the seed, until they hold the
-        mix's ``check_tokens`` cloud-served tokens: ``(tokens, served)``
-        with ``served`` the ``(position, token)`` pairs the cloud gave.
-        The ids of the streams taken are kept in ``checked``."""
+        """Finished pool streams that the cloud served: each replica's
+        longest first (on one chip, the longest), then in an order drawn
+        from the seed, until they hold the mix's ``check_tokens``
+        cloud-served tokens: ``(tokens, served)`` with ``served`` the
+        ``(position, token)`` pairs the cloud gave.  The ids of the
+        streams taken are kept in ``checked``."""
         done = [r for r in self.pool() if r.done and r.served]
         self.checked = []
         if not done:
             return []
         rng = np.random.default_rng([self.seed % (1 << 63), 7])
-        longest = max(range(len(done)), key=lambda j: len(done[j].seq))
+        longest = [max((j for j in range(len(done))
+                        if done[j].replica == r),
+                       key=lambda j: len(done[j].seq))
+                   for r in sorted({d.replica for d in done})]
         pick, served = [], 0
-        for j in [longest] + [j for j in rng.permutation(len(done))
-                              if j != longest]:
-            if served >= self.mix["check_tokens"]:
+        for j in longest + [j for j in rng.permutation(len(done))
+                            if j not in longest]:
+            if served >= self.mix["check_tokens"] and len(pick) >= len(
+                    longest):
                 break
             pick.append(j)
             served += len(done[j].served)
@@ -542,10 +621,10 @@ class Run:
 
     def free_program(self):
         """Drop the program's state (pools, weight copies) before the
-        reference runs; the benchmark's own weights stay."""
-        if self.engine is not None:
-            self.engine.cache = self.engine.params = None
-        self.engine = self.server = None
+        reference runs; the benchmark's weights on the first chip stay."""
+        for e in self.engines:
+            e.cache = e.params = None
+        self.engines, self.servers, self.server = [], [], None
 
     def check(self, streams=None) -> dict:
         """Compare every token the cloud served the sampled streams (or
@@ -572,10 +651,12 @@ class Run:
                               "min": self.mix["check_tokens"]},
             "tokens_out_of_vocab": {"value": int(not in_vocab), "limit": 0},
         }
+        # a limit of None is one the configuration does not state yet
+        # (its readings not measured): no run of it is correct
         self.correct = all(
-            c["value"] is not None and (c["value"] <= c["limit"]
-                                        if "limit" in c else
-                                        c["value"] >= c["min"])
+            c["value"] is not None and (
+                c["limit"] is not None and c["value"] <= c["limit"]
+                if "limit" in c else c["value"] >= c["min"])
             for c in checks.values())
         log("check", streams=len(streams), checked=self.checked,
             pool_streams=len(pool),

@@ -1,7 +1,9 @@
 """Model step: the operations the prefill and verify steps needed for
 the rows they fed (``chipbench/flops.py``: matmuls and attention over
 each row's real context, never padding), over their device time in the
-trace times the chip's peak."""
+trace times one chip's peak.  In a fleet the device time is summed over
+the chips, each call's on its own chip: the share of N chips' peak over
+the time they ran step programs."""
 
 
 def read(ctx):

@@ -1,7 +1,8 @@
 """Model step (``models/steps.py`` ``cloud_verify``): device time per
 verify call, from the trace: the ``jit_cloud_verify`` programs that ran
-inside each ``engine.feed`` host span (prefill runs the same program
-inside ``engine.prefill``, which the span tells apart)."""
+inside each ``engine.feed`` host span, on the chip of the engine that
+made the call (prefill runs the same program inside ``engine.prefill``,
+which the span tells apart)."""
 
 
 def read(ctx):

@@ -8,6 +8,12 @@ with token contents and with Poisson arrival times that the run's seed
 chooses; so a seed changes which stream is which and when it comes, not
 how much work a window holds.
 
+A prompt is either a length drawn from the mix (``lognormal``,
+``uniform``) or, for ``documents``, one of a fixed set of shared
+documents followed by the stream's own question: the seed writes the
+documents' tokens, their lengths are a fixed set, and each part's
+streams ask about them in fixed numbers that follow Zipf popularity.
+
 ``ReplayDevice`` stands in for ``DeviceRuntime`` on the cloud's side of
 the protocol: ``generate_steps`` yields the ``CloudCall``s a phone would
 send (the prompt prefill at arrival, then one verify per offloaded
@@ -49,17 +55,49 @@ class StreamSpec:
     draft_seed: int
 
 
+def zipf_ranks(n_docs: int, s: float, n: int) -> np.ndarray:
+    """``n`` stratified picks of a document's popularity rank (0 the most
+    asked about), rank ``r`` with weight ``1 / (r + 1) ** s``: each rank
+    gets its share of the picks, rounded, on every seed."""
+    w = 1.0 / np.arange(1, n_docs + 1, dtype=np.float64) ** s
+    cdf = np.cumsum(w) / w.sum()
+    q = (np.arange(n) + 0.5) / n
+    return np.minimum(np.searchsorted(cdf, q, side="right"), n_docs - 1)
+
+
+def documents(mix: dict, seed: int, vocab: int) -> list[list[int]] | None:
+    """The seed's shared documents in order of popularity, or None where
+    the mix shares none.  Their lengths are one fixed set (quantiles of
+    the mix's document length), paired with the ranks by the base seed,
+    so every seed asks as much of the cache; the tokens are the seed's."""
+    p = mix["prompt"]
+    if p["kind"] != "documents":
+        return None
+    n = p["documents"]
+    lens = _quantiles(p["length"], n)[
+        np.random.default_rng([BASE_SEED, 1 << 20]).permutation(n)]
+    rng = np.random.default_rng([int(seed) % (1 << 63), 5])
+    return [list(map(int, rng.integers(1, vocab, int(L)))) for L in lens]
+
+
 def draws(mix: dict, seed: int, n: int, part: int = 0) -> dict:
     """The sizes and offload patterns of ``n`` streams: one fixed set for
     part ``part`` of the schedule (quantiles of the mix's distributions,
     offload patterns from the base seed), in the order ``seed`` chooses.
-    Each stream's lengths keep the mix's distributions."""
+    Each stream's lengths keep the mix's distributions; for shared
+    documents, a stream draws the rank of its document (Zipf) and the
+    length of its question in place of a prompt length."""
     base = np.random.default_rng([BASE_SEED, part])
     run = np.random.default_rng([int(seed) % (1 << 63), 1, part])
-    out = {"prompt_len": _quantiles(mix["prompt"], n),
-           "out_len": _quantiles(mix["output"], n),
-           "offload": base.random((n, mix["output"]["max"] + mix["gamma"]))
-           < mix["offload_budget"]}
+    p = mix["prompt"]
+    if p["kind"] == "documents":
+        out = {"doc": zipf_ranks(p["documents"], p["zipf_s"], n),
+               "question_len": _quantiles(p["question"], n)}
+    else:
+        out = {"prompt_len": _quantiles(p, n)}
+    out.update(out_len=_quantiles(mix["output"], n),
+               offload=base.random((n, mix["output"]["max"] + mix["gamma"]))
+               < mix["offload_budget"])
     # a fixed pairing of the attributes into streams (base seed), then
     # the seed's order of the streams
     out = {k: v[base.permutation(n)] for k, v in out.items()}
@@ -81,6 +119,25 @@ def arrivals(mix: dict, seed: int, lo: float, hi: float, n: int,
     return np.sort(rng.uniform(lo, hi, n))
 
 
+def _streams(mix: dict, d: dict, times, docs, vocab: int, s_max: int,
+             first_sid: int) -> list[StreamSpec]:
+    out = []
+    for i, t in enumerate(times):
+        if docs is None:
+            prompt = list(map(int, d["rng"].integers(
+                1, vocab, int(d["prompt_len"][i]))))
+        else:
+            prompt = docs[d["doc"][i]] + list(map(int, d["rng"].integers(
+                1, vocab, int(d["question_len"][i]))))
+        # the cache holds prompt + output + one draft chunk at most
+        max_new = int(min(d["out_len"][i],
+                          s_max - mix["gamma"] - len(prompt)))
+        out.append(StreamSpec(first_sid + i, float(t), prompt, max_new,
+                              d["offload"][i],
+                              int(d["rng"].integers(1 << 62))))
+    return out
+
+
 def schedule(mix: dict, seed: int, edges_s: list, vocab: int,
              s_max: int) -> list[StreamSpec]:
     """Streams arriving in ``[0, edges_s[-1])`` for ``seed``.  Each part
@@ -88,23 +145,34 @@ def schedule(mix: dict, seed: int, edges_s: list, vocab: int,
     fixed set of ``rate x length`` streams, so every seed's window holds
     the same work, arriving as a Poisson process."""
     rate = mix["arrival"]["rate_per_s"]
-    gamma = mix["gamma"]
+    docs = documents(mix, seed, vocab)
     out = []
     lo = 0.0
     for part, hi in enumerate(edges_s):
         n = max(1, int(round(rate * (hi - lo))))
-        d = draws(mix, seed, n, part)
-        times = arrivals(mix, seed, lo, hi, n, part)
-        for i in range(n):
-            prompt = list(map(int, d["rng"].integers(
-                1, vocab, int(d["prompt_len"][i]))))
-            # the cache holds prompt + output + one draft chunk at most
-            max_new = int(min(d["out_len"][i], s_max - gamma - len(prompt)))
-            out.append(StreamSpec(len(out), float(times[i]), prompt, max_new,
-                                  d["offload"][i],
-                                  int(d["rng"].integers(1 << 62))))
+        out += _streams(mix, draws(mix, seed, n, part),
+                        arrivals(mix, seed, lo, hi, n, part), docs, vocab,
+                        s_max, len(out))
         lo = hi
     return out
+
+
+PRIME_PART = 1 << 10          # the draws of the priming streams
+
+
+def prime(mix: dict, seed: int, vocab: int, s_max: int,
+          first_sid: int) -> list[StreamSpec]:
+    """Where the mix says ``warm_documents``: one stream per document, in
+    order of popularity, each with its own question, that the warm-up
+    opens together before any arrival, so that the fleet holds every
+    document as a deployment's would.  Otherwise none."""
+    docs = documents(mix, seed, vocab)
+    if docs is None or not mix.get("warm_documents"):
+        return []
+    d = draws(mix, seed, len(docs), PRIME_PART)
+    d["doc"] = np.arange(len(docs))
+    return _streams(mix, d, np.zeros(len(docs)), docs, vocab, s_max,
+                    first_sid)
 
 
 # ---------------------------------------------------------------------------
@@ -129,6 +197,7 @@ class StreamRecord:
     seq: list = field(default_factory=list)
     session: object = None
     done: bool = False
+    replica: int = 0              # the replica serving it (-1: degraded)
 
 
 @dataclass

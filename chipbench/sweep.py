@@ -75,6 +75,10 @@ def main() -> int:
                        lifetime_p95_s=harness.percentile(life, 95),
                        lifetime_max_s=max(life),
                        lateness_max_ms=max(run.lateness_ms),
+                       counters={k: run.end[k] - run.base[k]
+                                 for k in run.end},
+                       prompt_tokens=run.counting.log.prompt_tokens,
+                       prompt_fed=run.counting.log.prompt_fed,
                        meets_limits=meets, **e2e)
             harness.log("sweep", **row)
             s = summary.setdefault(str(rate), {"meets_limits": True})

@@ -27,6 +27,7 @@ def main(config: str, traffic: str, trace: int) -> None:
 
     harness.peaks_for = lambda kind: CPU_PEAKS
     harness.Run = traced_run.TracedRun
+    harness.TRACE_DIR = harness.TRACE_DIR / "rehearse_traced"
     res = harness.execute(tiny_cell(config, traffic), 4_000_000_011, 2.0,
                           bool(trace), jax.devices()[:1], T0)
     print(json.dumps(res), flush=True)

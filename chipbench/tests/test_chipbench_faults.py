@@ -1,8 +1,12 @@
 """``correct`` comes out false when the timed path is broken underneath:
 a step that returns its state unchanged, half of the batch left out,
-and a token altered where it is produced; and when the control, the
-reference in a lower precision, takes the program's place.  The same
-run with the path intact comes out true."""
+and a token altered where it is produced, on one chip and on one
+replica of a four-replica fleet; and when the control, the reference in
+a lower precision, takes the program's place.  The same run with the
+path intact comes out true."""
+import json
+import subprocess
+import sys
 import time
 
 import jax
@@ -77,7 +81,7 @@ def test_the_control_in_the_programs_place_is_not_correct(seed):
              num_hidden_layers=4, vocab_size=32768)
     run = harness.Run(cell, seed, 1.0, False, jax.devices()[:1], 0.0)
     run.params = weights.make_params(run.cfg, seed, run.devices[0])
-    run.engine, run.unanswered, run.unfinished = None, 0, 0
+    run.unanswered, run.unfinished = 0, 0
     rng = np.random.default_rng(seed)
     seqs = [rng.integers(1, 32768, 400).astype(np.int32) for _ in range(6)]
     ref = harness._load_reference(m["reference"])
@@ -92,3 +96,43 @@ def test_the_control_in_the_programs_place_is_not_correct(seed):
     gap = run.check(low)["max_logit_gap"]
     assert run.correct is False
     assert gap["value"] > gap["limit"]
+
+
+@pytest.mark.parametrize("fault", [f for f in FAULTS if f != "intact"])
+def test_correct_catches_a_fault_on_one_replica_of_a_fleet(fault):
+    """The fleet's check takes every replica's longest stream, so a fault
+    on the last replica alone is not correct (the fleet intact is the
+    rehearsal's ``fleet4`` case)."""
+    from chipbench.tests.test_chipbench_rehearsal import (FOUR_DEVICES,
+                                                          ROOT, _env)
+
+    p = subprocess.run(
+        [sys.executable, "-m", "chipbench.tests.rehearse_fault", fault, "3"],
+        cwd=ROOT, capture_output=True, text=True, timeout=600,
+        env=_env(**FOUR_DEVICES))
+    assert p.returncode == 0, p.stderr[-3000:]
+    res = json.loads(p.stdout.strip().splitlines()[-1])
+    gap = res["checks"]["max_logit_gap"]
+    assert res["correct"] is False, res["checks"]
+    assert not gap["value"] <= gap["limit"]
+
+
+def test_a_configuration_without_a_limit_is_never_correct():
+    """A configuration whose ``max_logit_gap`` limit is not measured yet
+    (``null``) reports the gap it reads, and no run of it is correct,
+    even where every token is the reference's own first."""
+    cell = tiny_cell("qwen2-7b", "documents")
+    m = cell["model"]
+    m["check"] = {"max_logit_gap": None}
+    run = harness.Run(cell, 3, 1.0, False, jax.devices()[:1], 0.0)
+    run.params = weights.make_params(run.cfg, 3, run.devices[0])
+    run.unanswered, run.unfinished = 0, 0
+    tokens = np.random.default_rng(3).integers(1, 256, 200).astype(np.int32)
+    ref = harness._load_reference(m["reference"])
+    probe = [(tokens, [(p, 0) for p in range(20, 200)])]
+    (lg,) = ref.logits_at(m, run.params, probe, run.devices)
+    best = [(tokens, [(p, int(row.argmax())) for p, row in
+                      zip(range(20, 200), np.asarray(lg))])]
+    gap = run.check(best)["max_logit_gap"]
+    assert gap["value"] == 0.0 and gap["limit"] is None
+    assert run.correct is False

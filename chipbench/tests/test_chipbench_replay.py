@@ -138,3 +138,73 @@ def test_offload_budget_sets_the_verify_share():
     specs = replay.schedule(_mix("chat"), 5, [200.0], 32768, 2048)
     share = np.mean([s.offload[:64].mean() for s in specs])
     assert share == pytest.approx(_mix("chat")["offload_budget"], abs=0.02)
+
+
+def _docs_mix():
+    from chipbench.tests.tiny import DOCUMENTS
+
+    return DOCUMENTS
+
+
+@pytest.mark.parametrize("seed", [1, SEED])
+def test_every_seed_asks_the_same_questions_of_its_own_documents(seed):
+    """Shared documents: every seed's window holds the same number of
+    sessions, asking about documents of the same lengths with questions
+    and answers of the same lengths; each prompt is one of the seed's
+    own documents followed by a question."""
+    mix, vocab, s_max = _docs_mix(), 152064, 8192
+    edges = [30.0, 81.0, 141.0]
+
+    def window(sd):
+        return [s for s in replay.schedule(mix, sd, edges, vocab, s_max)
+                if 30.0 <= s.arrival_s < 81.0]
+
+    docs = replay.documents(mix, seed, vocab)
+    win, other = window(seed), window(seed + 1)
+    assert len(win) == len(other) == round(
+        mix["arrival"]["rate_per_s"] * 51)
+    assert len(docs) == mix["prompt"]["documents"]
+    lo, hi = mix["prompt"]["length"]["min"], mix["prompt"]["length"]["max"]
+    assert all(lo <= len(d) <= hi for d in docs)
+    assert sorted(map(len, docs)) == sorted(
+        map(len, replay.documents(mix, seed + 1, vocab)))
+
+    def key(s, ds):
+        j = [k for k, d in enumerate(ds) if s.prompt[:len(d)] == d]
+        assert len(j) == 1            # starts with exactly one document
+        q = len(s.prompt) - len(ds[j[0]])
+        assert mix["prompt"]["question"]["min"] <= q <= \
+            mix["prompt"]["question"]["max"]
+        assert len(s.prompt) + s.max_new + mix["gamma"] <= s_max
+        return (len(ds[j[0]]), q, s.max_new, s.offload.tobytes())
+
+    other_docs = replay.documents(mix, seed + 1, vocab)
+    assert sorted(key(s, docs) for s in win) == sorted(
+        key(s, other_docs) for s in other)
+    assert [s.prompt for s in win] != [s.prompt for s in other]
+
+
+def test_document_popularity_follows_zipf():
+    """Over many sessions each document's share of them is its Zipf
+    weight within sampling error."""
+    mix = _docs_mix()
+    p = mix["prompt"]
+    n = 20000
+    ranks = replay.draws(mix, 11, n, 1)["doc"]
+    w = 1.0 / np.arange(1, p["documents"] + 1) ** p["zipf_s"]
+    w /= w.sum()
+    counts = np.bincount(ranks, minlength=p["documents"])
+    assert counts.sum() == n
+    assert np.all(np.abs(counts - n * w) <= 3 * np.sqrt(n * w * (1 - w)))
+    assert counts[0] > counts[1] > counts[-1] > 0
+
+
+def test_the_warm_up_opens_one_stream_per_document_in_order_of_popularity():
+    mix, vocab = _docs_mix(), 152064
+    docs = replay.documents(mix, SEED, vocab)
+    primed = replay.prime(mix, SEED, vocab, 8192, 1000)
+    assert len(primed) == len(docs)
+    assert [s.sid for s in primed] == list(range(1000, 1000 + len(docs)))
+    assert all(s.arrival_s == 0.0 and s.prompt[:len(d)] == d
+               and len(s.prompt) > len(d) for s, d in zip(primed, docs))
+    assert replay.prime(_mix("chat"), SEED, 32768, 2048, 0) == []

@@ -1,6 +1,8 @@
 """The trace reduction on a synthetic trace: busy/idle union, kernel time
 by name, attribution of idle gaps to host spans, and the readers built
 on them."""
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
@@ -102,3 +104,62 @@ def test_readers_return_nothing_without_a_trace():
                  "device_idle_share", "padded_row_share",
                  "prefix_hit_share"):
         assert _load_reader(name)(ctx) is None
+
+
+def _fleet_trace():
+    """Two replicas on chips 0 and 2: a verify of replica 1 runs on chip
+    2 while chip 0 idles, then replica 0's verify runs on chip 0."""
+    ev = tracing.Event
+    ops = {0: [ev("%fusion.1 = bf16[8] fusion(x)", 0.50, 0.60, 0)],
+           2: [ev("%fusion.1 = bf16[8] fusion(x)", 0.12, 0.40, 2)]}
+    mods = {0: [ev("jit_cloud_verify(1)", 0.50, 0.60, 0)],
+            2: [ev("jit_cloud_verify(1)", 0.12, 0.40, 2)]}
+
+    def raw(name, t0, t1):
+        return SimpleNamespace(name=name, start_ns=int(t0 * 1e9),
+                               duration_ns=int((t1 - t0) * 1e9))
+
+    spans = [tracing._span(e, [0, 2]) for e in (
+        raw("bench.traced", 0.0, 1.0), raw("bench.step.r1", 0.1, 0.45),
+        raw("bench.verify.r1", 0.11, 0.42), raw("bench.step.r0", 0.48, 0.62),
+        raw("bench.verify.r0", 0.49, 0.61))]
+    return tracing.TraceData((0.0, 1.0), ops, mods, spans, [0, 2])
+
+
+def test_a_replicas_spans_read_the_chip_that_served_it():
+    tr = _fleet_trace()
+    assert [(s.name, s.device) for s in tr.spans[1:3]] == [
+        ("bench.step", 2), ("bench.verify", 2)]
+    assert [s.device for s in tr.spans_named("bench.verify")] == [2, 0]
+    # per call, each on its own chip: (0.28 + 0.10) / 2
+    ctx = dict(trace=tr, counts=CallLog())
+    assert _load_reader("verify_step_ms")(ctx) == pytest.approx(190.0)
+    assert tr.innermost(0.3) == "replica on TPU2: " + tracing.SPAN_NAMES[
+        "bench.verify"]
+    # chip 0 idles from 0 to 0.5 s while the host serves replica 1
+    assert ["TPU0: replica on TPU2: " + tracing.SPAN_NAMES["bench.verify"],
+            pytest.approx(0.5)] in tr.breakdown()["idle_gaps"]
+    assert _load_reader("device_idle_share")(ctx) == pytest.approx(
+        100 * (1 - (0.10 + 0.28) / 2))
+    assert _load_reader("replica_busy_skew")(ctx) == pytest.approx(
+        0.28 / ((0.10 + 0.28) / 2))
+
+
+def test_a_chip_with_nothing_traced_counts_as_idle():
+    tr = _fleet_trace()
+    tr.chips = [0, 2, 3]
+    assert tr.busy_s == pytest.approx((0.10 + 0.28) / 3)
+    assert _load_reader("replica_busy_skew")(dict(trace=tr)) == \
+        pytest.approx(0.28 / ((0.10 + 0.28) / 3))
+    assert any(g[0].startswith("TPU3") for g in tr.breakdown()["idle_gaps"])
+
+
+def test_affinity_share_reads_the_routers_hits_in_the_window():
+    read = _load_reader("affinity_share")
+    base = dict(opened=10, affinity_hits=6)
+    assert read(dict(base=base, end=dict(opened=30, affinity_hits=24))) == \
+        pytest.approx(90.0)
+    # one chip: no router, nothing to read
+    assert read(dict(base=dict(opened=1), end=dict(opened=5))) is None
+    assert read(dict(base=base, end=base)) is None
+    assert _load_reader("replica_busy_skew")(dict(trace=None)) is None

@@ -50,11 +50,14 @@ class TracedRun(harness.Run):
         from repro.serving.server import SyneraServer
         from repro.serving.trace import Tracer
 
+        if len(self.devices) != 1:
+            raise SystemExit("chipbench traced_run: one-chip cells only")
         super().build()
         self.tracer = Tracer(self.clock)
-        self.server = SyneraServer(self.device, self.engine,
+        self.server = SyneraServer(self.device, self.engines[0],
                                    chunk=self.sv["chunk"], clock=self.clock,
                                    tracer=self.tracer)
+        self.servers = [self.server]
         self._watch_prefills()
 
     def _counters(self) -> dict:

@@ -7,10 +7,14 @@ A TPU plane is ``/device:TPU:<id>``; its ``XLA Ops`` line holds one event
 per HLO op run on the core (the name is the op's HLO text) and its
 ``XLA Modules`` line one event per program run (``jit_<name>(<hash>)``).
 Host spans are the ``bench.*`` events the harness opens with
-``TraceAnnotation``; ``bench.traced`` spans the traced window.
+``TraceAnnotation``; ``bench.traced`` spans the traced window.  In a
+fleet, a replica's spans end in ``.r<i>`` (``bench.verify.r2``): the
+reduction strips that and gives the span the chip of replica ``i``, and
+the device time read inside it is that chip's alone.
 """
 from __future__ import annotations
 
+import re
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -31,6 +35,10 @@ class Span:
     name: str
     t0: float          # seconds on the trace clock
     t1: float
+    device: int | None = None   # the chip of the replica it served
+
+
+REPLICA = re.compile(r"^(bench\.\w+)\.r(\d+)$")
 
 
 @dataclass
@@ -47,6 +55,12 @@ class TraceData:
     ops: dict = field(default_factory=dict)       # device -> [Event]
     modules: dict = field(default_factory=dict)   # device -> [Event]
     spans: list = field(default_factory=list)     # [Span], host
+    chips: list = field(default_factory=list)     # device ids used
+
+    @property
+    def devices(self) -> list:
+        """The chips the run used, an idle one too; else those traced."""
+        return sorted(self.chips) or sorted(self.ops) or [0]
 
     @property
     def window_s(self) -> float:
@@ -61,19 +75,27 @@ class TraceData:
     @property
     def busy_s(self) -> float:
         """Seconds in which an op ran, averaged over the chips used."""
-        devs = sorted(self.ops) or [0]
-        return float(np.mean([length(self.busy_intervals(d)) for d in devs]))
+        return float(np.mean(self.busy_per_chip()))
+
+    def busy_per_chip(self) -> list:
+        return [length(self.busy_intervals(d)) for d in self.devices]
 
     def spans_named(self, *names) -> list:
         return [s for s in self.spans if s.name in names
                 and s.t0 >= self.window[0] and s.t1 <= self.window[1]]
 
+    def _on(self, events: dict, span: Span) -> list:
+        """The events of the span's chip, or of every chip."""
+        if span.device is not None:
+            return events.get(span.device, [])
+        return [e for d in events.values() for e in d]
+
     def modules_in(self, span: Span, prefix: str) -> list:
-        return [m for d in self.modules.values() for m in d
+        return [m for m in self._on(self.modules, span)
                 if m.name.startswith(prefix) and span.t0 <= m.t0 < span.t1]
 
     def ops_in(self, span: Span, match) -> list:
-        return [o for d in self.ops.values() for o in d
+        return [o for o in self._on(self.ops, span)
                 if span.t0 <= o.t0 < span.t1 and match(o.name)]
 
     def innermost(self, t: float) -> str:
@@ -82,7 +104,12 @@ class TraceData:
             if s.t0 <= t < s.t1 and (best is None
                                      or s.t1 - s.t0 < best.t1 - best.t0):
                 best = s
-        return SPAN_NAMES.get(best.name, best.name) if best else "outside spans"
+        if best is None:
+            return "outside spans"
+        what = SPAN_NAMES.get(best.name, best.name)
+        if best.device is None:
+            return what
+        return f"replica on TPU{best.device}: {what}"
 
     def breakdown(self, top: int = 10) -> dict:
         """The device ops that took most time (by program and op), and the
@@ -102,7 +129,7 @@ class TraceData:
                 tot[key] = tot.get(key, 0.0) + spent
         ops = sorted(tot.items(), key=lambda kv: -kv[1])[:top]
         gaps = []
-        for d in sorted(self.ops):
+        for d in self.devices:
             busy = self.busy_intervals(d)
             edges = np.concatenate([[self.window[0]], busy.ravel(),
                                     [self.window[1]]]).reshape(-1, 2)
@@ -151,7 +178,8 @@ def length(iv: np.ndarray) -> float:
 
 
 def reduce(path: str, devices=None) -> TraceData:
-    """Read the trace at ``path``; ``devices`` (ids) limits the chips."""
+    """Read the trace at ``path``; ``devices`` (ids, replica ``i`` on
+    ``devices[i]``) limits the chips."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
@@ -171,15 +199,24 @@ def reduce(path: str, devices=None) -> TraceData:
             for line in plane.lines:
                 for e in line.events:
                     if e.name.startswith("bench."):
-                        spans.append(Span(e.name, e.start_ns * 1e-9,
-                                          (e.start_ns + e.duration_ns) * 1e-9))
+                        spans.append(_span(e, devices))
     win = [s for s in spans if s.name == "bench.traced"]
     if win:
         window = (win[0].t0, win[0].t1)
     else:
         allev = [e for d in ops.values() for e in d]
         window = (min(e.t0 for e in allev), max(e.t1 for e in allev))
-    return TraceData(window, ops, modules, spans)
+    return TraceData(window, ops, modules, spans, list(devices or []))
+
+
+def _span(e, devices) -> Span:
+    name, device = e.name, None
+    m = REPLICA.match(name)
+    if m:
+        r = int(m.group(2))
+        name, device = m.group(1), devices[r] if devices else r
+    return Span(name, e.start_ns * 1e-9,
+                (e.start_ns + e.duration_ns) * 1e-9, device)
 
 
 def _events(line, dev: int) -> list:
